@@ -17,6 +17,9 @@ CACHE = os.path.join(os.path.dirname(__file__), "..", "results",
                      "serve_grid.json")
 SYSTEMS = ("fast-dllm", "dllm-cache", "sparse-dllm", "dllm-serve")
 WORKLOADS = ("livebench", "burst", "osc")
+# slot-sizing budget for these CPU runs (XLA:CPU reports no device memory
+# limit): one TPU v5e chip's 16 GiB
+CPU_HBM_BYTES = 16 << 30
 
 
 def grid(quick: bool = True, refresh: bool = False) -> list:
@@ -36,7 +39,8 @@ def grid(quick: bool = True, refresh: bool = False) -> list:
                               max_seq_len=192, block_size=8,
                               steps_per_block=8, max_slots=12,
                               max_num_batched_tokens=768,
-                              max_num_logits=96, length_scale=0.12)
+                              max_num_logits=96, length_scale=0.12,
+                              hbm_bytes=CPU_HBM_BYTES)
                 rows.append(r)
                 with open(CACHE, "w") as f:
                     json.dump(rows, f, indent=1)

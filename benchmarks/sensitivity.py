@@ -1,13 +1,14 @@
 """Paper Fig.7: relative throughput (dLLM-Serve / Sparse-dLLM) vs input and
 output length. The paper observes speedups decaying from ~3.1x to ~2.5x as
 lengths grow (longer atomic Refresh phases are harder to interleave)."""
+from benchmarks._grid import CPU_HBM_BYTES
 from repro.launch.serve import run_serve
 
 
 def _pair(workload, in_len, out_len, seed=0):
     kw = dict(max_seq_len=256, block_size=8, steps_per_block=8, max_slots=10,
               max_num_batched_tokens=1024, max_num_logits=128,
-              length_scale=1.0, time_scale=0.02)
+              length_scale=1.0, time_scale=0.02, hbm_bytes=CPU_HBM_BYTES)
     import repro.data.workloads as W
     orig = W.make_trace
 

@@ -9,7 +9,8 @@ rows (1×1 vs 1×2 host-device subprocess runs: per-device exec tokens +
 modeled throughput, tracking the sharded-serving trajectory).
 
 Flags and the row schema are documented in ``docs/benchmarks.md``."""
-from benchmarks._grid import SYSTEMS, WORKLOADS, best_baseline, grid, ours
+from benchmarks._grid import (CPU_HBM_BYTES, SYSTEMS, WORKLOADS,
+                              best_baseline, grid, ours)
 from repro.launch.serve import run_serve
 
 # one arch per packed execution path: dense attention, SSM scan, hybrid,
@@ -38,7 +39,7 @@ def per_arch_waste(quick: bool = True):
                 arch, sys_name, "burst", 2.0, 8, max_seq_len=192,
                 block_size=8, steps_per_block=8, max_slots=8,
                 max_num_batched_tokens=768, max_num_logits=96,
-                length_scale=0.12)
+                length_scale=0.12, hbm_bytes=CPU_HBM_BYTES)
         pk, pd = res["dllm-serve"], res["fast-dllm"]
         for stage in ("refresh", "reuse", "logit"):
             out.append((
@@ -68,6 +69,14 @@ def _mesh_serve(mesh: str, n: int, kernels: bool) -> dict:
     key = (mesh, n, kernels)
     if key in _MESH_SERVE_CACHE:
         return _MESH_SERVE_CACHE[key]
+    import jax
+    if jax.default_backend() == "tpu":
+        # these rows measure CPU host devices in child processes; on a TPU
+        # host the children could not reach the chip this process holds,
+        # and a CPU number must never stand in for the device
+        raise RuntimeError(
+            "throughput mesh rows run on CPU host devices only; this "
+            "harness process holds a TPU backend")
     import json
     import os
     import subprocess
@@ -94,7 +103,8 @@ def _mesh_serve(mesh: str, n: int, kernels: bool) -> dict:
         cmd = [sys.executable, "-m", "repro.launch.serve",
                "--arch", "llada-8b", "--system", "dllm-serve",
                "--workload", "burst", "--rps", str(MESH_RPS), "--n", str(n),
-               "--mesh", mesh, "--out", path]
+               "--mesh", mesh, "--hbm-gb", str(CPU_HBM_BYTES >> 30),
+               "--out", path]
         if kernels:
             cmd.append("--kernels")
         r = subprocess.run(cmd, capture_output=True, text=True, env=env,

@@ -23,7 +23,7 @@ def main():
     rows = []
     for system in ("fast-dllm", "dllm-cache", "sparse-dllm", "dllm-serve"):
         r = run_serve("llada-8b", system, args.workload, args.rps, args.n,
-                      time_scale=0.02)
+                      time_scale=0.02, hbm_bytes=16 << 30)
         rows.append(r)
         print(f"{system:12s} tput={r['throughput_tok_s']:8.1f} tok/s  "
               f"avg_lat={r['avg_latency']:7.2f}s  p99={r['p99_latency']:7.2f}s")

@@ -22,6 +22,7 @@ from repro.configs.phi35_moe import CONFIG as _phi35_moe
 from repro.configs.zamba2_7b import CONFIG as _zamba2_7b
 from repro.configs.internvl2_76b import CONFIG as _internvl2_76b
 from repro.configs.llada_8b import CONFIG as _llada_8b
+from repro.configs.llada_8b_1chip import CONFIG as _llada_8b_1chip
 
 ARCHS = {
     "gemma-2b": _gemma_2b,
@@ -36,9 +37,11 @@ ARCHS = {
     "internvl2-76b": _internvl2_76b,
     # the paper's own model (not part of the assigned 10, used by examples)
     "llada-8b": _llada_8b,
+    # its published widths at half depth, sized for one 16 GB TPU v5e chip
+    "llada-8b-1chip": _llada_8b_1chip,
 }
 
-ASSIGNED = tuple(k for k in ARCHS if k != "llada-8b")
+ASSIGNED = tuple(k for k in ARCHS if not k.startswith("llada-8b"))
 
 
 def get_config(name: str) -> ModelConfig:

@@ -157,7 +157,6 @@ class ServeConfig:
     # keeping waste < one bucket, vs up-to-2× for power-of-two padding)
     use_flash_kernel: bool = False        # pallas attention in engine steps
     vocab_tile: int = 1024               # V-tile for the fused logit kernel
-    dtype: str = "float32"
     # --- mesh serving (tensor-parallel packed pipeline) ----------------------
     mesh_shape: Optional[Tuple[int, ...]] = None
     # (data, model) device mesh the engine executes under. None = no mesh

@@ -150,7 +150,7 @@ def _slot_cache_shapes(cfg: ModelConfig, serve: ServeConfig, retain: int,
     import jax.numpy as jnp
     from repro.models.sparse_select import PackedKV
     sds = jax.ShapeDtypeStruct
-    dt = jnp.dtype(serve.dtype)
+    dt = jnp.dtype(cfg.dtype)
 
     def kv_tree(nl):
         kshape = (nl, batch, cfg.n_kv_heads, retain, cfg.resolved_head_dim)
@@ -307,7 +307,7 @@ def backbone_activation_bytes(cfg: ModelConfig, serve: ServeConfig) -> int:
     stays replicated), so the reservation is per device. The packed (and
     sharded) engine's smaller reservation is converted into KV slots by
     :func:`plan_memory`."""
-    b = dtype_bytes(serve.dtype)
+    b = dtype_bytes(cfg.dtype)
     m = serve.mesh_model
     T = max(max_exec_tokens(serve, cfg), reuse_exec_tokens(serve, cfg))
     width = max(cfg.d_ff // _tp_div(cfg.d_ff, m),

@@ -330,8 +330,6 @@ class Engine:
         self.device = device_model or DeviceModel()
         self.vtime = 0.0
         self._n_params = cfg.n_active_params()
-        if params is None:
-            params = BB.init_params(cfg, jax.random.PRNGKey(seed))
         self.mask_id = diffusion.mask_token_id(cfg.vocab_size)
         retain = min(serve.retained_len,
                      serve.max_seq_len - serve.block_size)
@@ -379,7 +377,7 @@ class Engine:
             pshapes = jax.eval_shape(_partial(BB.init_params, cfg),
                                      jax.random.PRNGKey(0))
             self._pspecs = self.rules.params(pshapes)
-            params = jax.device_put(params, self.rules.named(self._pspecs))
+            param_shardings = self.rules.named(self._pspecs)
             # ONE cache layout for every *stream* — gathered sub-batches and
             # fresh Refresh caches (data_parallel=False: only the model axis
             # shards within a slot) — batch-size-dependent specs would
@@ -415,6 +413,7 @@ class Engine:
         else:
             self.rules = None
             self._pspecs = None
+            param_shardings = None
             # the policy is process-global: a later single-device engine must
             # not trace against a previous mesh engine's stale NamedShardings
             # (the newest engine owns the policy — one serving mesh per
@@ -422,6 +421,14 @@ class Engine:
             # own processes and never construct an Engine)
             from repro.models import layers as Lmod
             Lmod.set_sharding_policy({})
+        if params is None:
+            # built on device, each leaf directly in the sharding it lives
+            # in: no device (and not the host) ever holds the whole model
+            params = JC.jit(partial(BB.init_params, cfg),
+                            out_shardings=param_shardings)(
+                jax.random.PRNGKey(seed))
+        elif param_shardings is not None:
+            params = jax.device_put(params, param_shardings)
         self.params = params
         self.scheduler = make_scheduler(serve)
         # retrace sentinel: every jit entry point of THIS engine (stage jits
@@ -1422,11 +1429,23 @@ class Engine:
         carries its ``frontend_len`` projected prefix rows ahead of the
         text tokens, already accounted in those offsets. Returns (block
         hidden, executed tokens = the token bucket)."""
-        chunk = seg_layout.requests
-        cu_real = seg_layout.cu_seqlens
+        chunk = list(seg_layout.requests)
+        n = len(chunk)
+        self._check_slots(chunk)
+        out, tp, rp = self._refresh_packed(chunk, seg_layout.cu_seqlens)
+        self._pool_write(chunk, out.cache, rp - n)
+        self.stats.packed_refresh_calls += 1
+        self.stats.refresh_tokens_real += seg_layout.total_tokens
+        self.stats.refresh_tokens_exec += tp
+        return out.block_hidden[:n], tp
+
+    def _refresh_packed(self, chunk: List[Request], cu_real):
+        """Fill one packed Refresh stream for ``chunk`` (segment j starts at
+        flat row ``cu_real[j]``) and dispatch it. Returns (RefreshOut,
+        token bucket, request bucket); the pool is not touched."""
         n = len(chunk)
         rp = _bucket(n)
-        t_real = seg_layout.total_tokens
+        t_real = int(cu_real[n])
         tp = self._token_bucket(t_real)
         F = self._fe_len
         tokens = np.zeros((tp,), np.int32)
@@ -1458,18 +1477,24 @@ class Engine:
             bstart[j] = F + r.block_start
             if F:
                 fe[j] = r.frontend
-        self._check_slots(list(chunk))
         out = self._dispatch("refresh", lambda: self._refresh_packed_fn(
             tp, rp)(
             self.params, jnp.asarray(tokens), jnp.asarray(pos),
             jnp.asarray(seg), jnp.asarray(valid), jnp.asarray(cu),
             jnp.asarray(lens), jnp.asarray(bstart),
             jnp.asarray(fe) if F else None))
-        self._pool_write(list(chunk), out.cache, rp - n)
-        self.stats.packed_refresh_calls += 1
-        self.stats.refresh_tokens_real += t_real
-        self.stats.refresh_tokens_exec += tp
-        return out.block_hidden[:n], tp
+        return out, tp, rp
+
+    def refresh_outputs(self, reqs: List[Request]):
+        """The packed Refresh stage over ``reqs`` as one stream, through the
+        engine's own stage jit — what an iteration that refreshes exactly
+        these requests computes — without writing the slot pool or counting
+        stats. Returns the RefreshOut (block hidden ``[n, Sb, D]`` first,
+        then request-bucket padding). The entry point of the agreement
+        checks against the padded oracle and across meshes."""
+        cu = np.concatenate([[0], np.cumsum([r.refresh_len for r in reqs])])
+        out, _, _ = self._refresh_packed(list(reqs), cu)
+        return out
 
     def _run_reuse(self, reqs: List[Request]) -> Tuple[jax.Array, int]:
         """Padded-oracle Reuse: pow2 request bucket, scratch-slot pad rows.

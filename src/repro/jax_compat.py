@@ -1,25 +1,14 @@
-"""Version shims for the pinned jax 0.4.37 vs the newer mesh-context APIs.
+"""The one doorway to jax's mesh, sharding and jit APIs.
 
-The codebase targets the modern spelling (``jax.set_mesh`` /
-``jax.sharding.get_abstract_mesh`` / ``jax.shard_map``) but the container pins
-jax 0.4.37, where none of these exist. Each helper prefers the modern API and
-falls back to the 0.4.37 equivalent:
-
-  * mesh context — ``jax.set_mesh(mesh)`` vs the ``with mesh:`` resource
-    context (``thread_resources.env.physical_mesh``).
-  * active-mesh query — ``jax.sharding.get_abstract_mesh()`` vs reading the
-    thread-resource physical mesh. Both are normalized to *None when no mesh
-    is active* so call sites need a single emptiness check.
-  * shard_map — ``jax.shard_map(..., check_vma=)`` vs
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.
-
-This module is also the ONLY sanctioned doorway to the mesh/sharding API and
-to ``jax.jit`` on the serving hot paths (the invariant ``repro.analysis``
-lints for): ``P`` re-exports ``PartitionSpec`` so no other module imports
-``jax.sharding`` directly, and :func:`jit` / :func:`jit_sharded` wrap
-``jax.jit`` with an optional per-entry-point **compile counter** — the
-retrace sentinel (``repro.analysis.retrace``) reads those counters to prove
-the steady-state serving loop never recompiles after warmup.
+Every other module reaches the mesh context (``jax.set_mesh``), the
+active-mesh query (``jax.sharding.get_abstract_mesh``), ``jax.shard_map``,
+``PartitionSpec`` and ``jax.jit`` through here — the invariant
+``repro.analysis`` lints for. ``P`` re-exports ``PartitionSpec`` so no other
+module imports ``jax.sharding`` directly, and :func:`jit` /
+:func:`jit_sharded` wrap ``jax.jit`` with an optional per-entry-point
+**compile counter** — the retrace sentinel (``repro.analysis.retrace``)
+reads those counters to prove the steady-state serving loop never
+recompiles after warmup.
 
 Keep this module dependency-free (imported by kernels, models, and launch).
 """
@@ -36,10 +25,10 @@ __all__ = [
     "jit_sharded", "shard_map", "compile_counts", "reset_compile_counts",
 ]
 
-# On CPU (and some older backends) jax 0.4.37 cannot alias every donated
-# buffer and warns "Some donated buffers were not usable" per dispatch.
-# Donation is a pure lifetime hint — numerics are identical either way — so
-# when a caller opts into donation we silence exactly that message once.
+# XLA:CPU cannot alias every donated buffer, and jax warns "Some donated
+# buffers were not usable" on each such compile. Donation is a pure lifetime
+# hint — numerics are identical either way — so when a caller opts into
+# donation we silence exactly that message once.
 _DONATION_WARNING_FILTERED = False
 
 
@@ -57,7 +46,7 @@ def _enable_donation(jit_kwargs: dict, donate_argnums) -> dict:
 # process-global trace/compile counters, keyed by entry-point name. A jitted
 # function's Python body runs exactly once per cache miss (each trace lowers
 # and compiles), so counting body executions counts compilations — no
-# version-fragile jax.monitoring hook needed on the pinned 0.4.37.
+# version-fragile jax.monitoring hook needed.
 _compile_counts: collections.Counter = collections.Counter()
 
 
@@ -102,8 +91,8 @@ def jit(fn=None, *, entry=None, counter=None, donate_argnums=(),
     buffers so packed streams stop double-buffering — docs/engine.md).
     The caller contract: a donated argument's buffer is dead after the
     call; never re-pass or read it. Backends that can't alias a given
-    donation silently keep a copy (the 0.4.37 CPU warning is filtered
-    here), so donation never changes numerics — only buffer lifetime."""
+    donation silently keep a copy (the CPU warning is filtered here), so
+    donation never changes numerics — only buffer lifetime."""
     if fn is None:
         import functools
         return functools.partial(jit, entry=entry, counter=counter,
@@ -115,38 +104,25 @@ def jit(fn=None, *, entry=None, counter=None, donate_argnums=(),
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """Activate ``mesh`` for the dynamic scope (modern ``jax.set_mesh``)."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        # 0.4.37: Mesh is itself a context manager that installs the
-        # thread-resource physical mesh (what get_active_mesh reads back).
-        with mesh:
-            yield mesh
+    """Activate ``mesh`` for the dynamic scope (``jax.set_mesh``)."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def get_active_mesh():
-    """The mesh of the enclosing ``use_mesh`` scope, or None.
+    """The ``AbstractMesh`` of the enclosing ``use_mesh`` scope, or None.
 
-    Returns an ``AbstractMesh`` on modern jax and a concrete ``Mesh`` on
-    0.4.37 — both expose ``axis_names``/``shape``, which is all call sites
-    use. Never returns an *empty* mesh object.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        m = fn()
-        return None if m is None or m.empty else m
-    from jax._src import mesh as _mesh_lib
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    Never returns an *empty* mesh object, so call sites need a single
+    emptiness check; they read only ``axis_names``/``shape``."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
 def named_shardings(mesh, spec_tree):
     """PartitionSpec pytree -> NamedSharding pytree on ``mesh``.
 
-    Specs are the *leaves* (a PartitionSpec is itself a pytree on some jax
-    versions, so tree ops must treat it atomically)."""
+    Specs are the *leaves* (tree ops must treat a PartitionSpec
+    atomically)."""
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, PartitionSpec))
@@ -158,10 +134,9 @@ def jit_sharded(fn, *, mesh, in_specs=None, out_specs=None, entry=None,
 
     The serving engine's per-stage entry points thread their stage layouts
     through here: host inputs are auto-placed to the given in_specs (a spec
-    leaf broadcasts over optional ``None`` args — verified on the pinned
-    0.4.37), outputs are pinned to out_specs so downstream consumers (the
-    slot pool above all) see a stable layout instead of whatever GSPMD
-    propagation happened to pick. ``mesh=None`` is a plain ``jax.jit`` —
+    leaf broadcasts over optional ``None`` args), outputs are pinned to
+    out_specs so downstream consumers (the slot pool above all) see a
+    stable layout instead of whatever GSPMD propagation happened to pick. ``mesh=None`` is a plain ``jax.jit`` —
     the single-device path stays byte-for-byte the old code path.
 
     ``entry``/``counter`` hook the retrace sentinel exactly as in
@@ -182,11 +157,6 @@ def jit_sharded(fn, *, mesh, in_specs=None, out_specs=None, entry=None,
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the 0.4.37 ``check_rep`` spelling fallback."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+    """``jax.shard_map`` (the lint-sanctioned spelling)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

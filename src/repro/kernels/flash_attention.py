@@ -73,9 +73,9 @@ def packed_flash_attention_call(
     v: jax.Array,        # [B, K, T, dh]
     mask: jax.Array,     # [B, K, Sb, T] bool
     *,
+    interpret: bool,
     softcap: float = 0.0,
     t_tile: int = 512,
-    interpret: bool = True,
 ):
     B, K, R, dh = q.shape
     T = k.shape[2]

@@ -95,12 +95,12 @@ def flash_refresh_call(
     kv_valid: jax.Array,  # [B, S] bool
     is_local: jax.Array,  # [1] bool (runtime: gemma2 alternating layers)
     *,
+    interpret: bool,
     softcap: float = 0.0,
     causal: bool = False,
     window: int = 0,
     q_tile: int = 256,
     kv_tile: int = 512,
-    interpret: bool = True,
 ):
     B, K, RG, dh = q.shape
     S = k.shape[2]                 # KV length

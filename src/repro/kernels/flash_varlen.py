@@ -9,17 +9,24 @@ in-kernel segment masking — a query attends to a key iff both tokens belong
 to the same request. No cross-request attention, and no ``[S, S]`` bias is
 ever materialized.
 
-Grid ``(K, n_q, n_kv)`` (KV innermost), flash online-softmax accumulation as
-in :mod:`flash_refresh`, plus a **tile-skip**: segment ids are ascending
-along the stream, so a KV tile whose segment range does not intersect the
-query tile's range is skipped entirely (only the init/normalize bookkeeping
-runs). That is what makes packed-attention FLOPs track ``Σ S_i²`` rather
-than ``T_total²`` at tile granularity.
+Grid ``(K, n_q, n_kv)`` (KV innermost), flash online-softmax accumulation
+with the running max/sum in VMEM scratch, plus a **tile-skip**: segment ids
+are ascending along the stream, so a KV tile whose segment range does not
+intersect the query tile's range is skipped entirely (only the
+init/normalize bookkeeping runs). That is what makes packed-attention FLOPs
+track ``Σ S_i²`` rather than ``T_total²`` at tile granularity. The per-tile
+segment ranges are reduced in XLA and read from SMEM as scalars.
 
-Masking inputs are per-token 1-D arrays: ``pos`` (position *within* the
-request — drives causal and sliding-window masks), ``seg`` (request id),
-``valid`` (False on bucket padding). GQA rows are token-major flattened
-(row = t·G + g) exactly like the refresh kernel.
+Masking inputs are per-token: ``pos`` (position *within* the request —
+drives causal and sliding-window masks), ``seg`` (request id), ``valid``
+(False on bucket padding). GQA rows are token-major flattened (row = t·G +
+g), so the query-side arrays are expanded to one entry per row. Layouts
+follow the TPU block rules: query-side arrays are ``[n_q, rows, 1]``
+columns, KV-side arrays ``[..., n_kv, 1, kv_tile]`` rows, so every block's
+last two dims equal the array's.
+
+The cross-attention variant (the Reuse phase) runs the same kernel with
+distinct query/KV streams and per-KV-head KV positions/validity.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import jax_compat as JC
 
@@ -37,59 +45,119 @@ from repro import jax_compat as JC
 PAD_SEG = (1 << 30)
 
 
-def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, qseg_ref, kseg_ref,
-            kvalid_ref, loc_ref, o_ref, m_ref, s_ref,
-            *, scale: float, softcap: float, g: int, causal: bool,
-            window: int, n_kv: int):
-    j = pl.program_id(2)
+def _kernel(qrng_ref, krng_ref, loc_ref, q_ref, k_ref, v_ref, qpos_ref,
+            qseg_ref, kpos_ref, kseg_ref, kvalid_ref, o_ref, m_sc, s_sc,
+            *, scale: float, softcap: float, causal: bool, window: int,
+            n_kv: int):
+    i, j = pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        s_ref[...] = jnp.zeros_like(s_ref)
+        m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
+        s_sc[...] = jnp.zeros_like(s_sc)
 
-    qs = qseg_ref[...]             # [q_tile]
-    ks = kseg_ref[...]             # [Tk]
     # tile-skip: streams are segment-ascending, so disjoint id ranges cannot
     # share a request — skip the matmul + softmax update entirely.
-    overlap = (jnp.min(qs) <= jnp.max(ks)) & (jnp.min(ks) <= jnp.max(qs))
+    overlap = (qrng_ref[0, i] <= krng_ref[1, j]) & \
+        (krng_ref[0, j] <= qrng_ref[1, i])
 
     @pl.when(overlap)
     def _compute():
-        q = q_ref[0]               # [R, dh]  (R = q_tile * G)
+        q = q_ref[0]               # [R, dh]  (R = q_tile * G rows)
         k = k_ref[0]               # [Tk, dh]
         v = v_ref[0]
-        qp = qpos_ref[...]         # [q_tile]
-        kp = kpos_ref[...]         # [Tk]
-        kv = kvalid_ref[...]       # [Tk]
-
-        z = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
         if softcap:
             z = softcap * jnp.tanh(z / softcap)
-        ok = kv[None, :] & (qs[:, None] == ks[None, :])
+        qp = qpos_ref[0]           # [R, 1]
+        kp = kpos_ref[0, 0]        # [1, Tk]
+        ok = (qseg_ref[0] == kseg_ref[0]) & (kvalid_ref[0, 0] != 0)
         if causal:
-            ok = ok & (qp[:, None] >= kp[None, :])
+            ok = ok & (qp >= kp)
         if window:
-            loc = loc_ref[0]
-            ok = ok & ((jnp.abs(qp[:, None] - kp[None, :]) <= window) | ~loc)
-        R, Tk = z.shape
-        zm = jnp.where(ok[:, None, :], z.reshape(R // g, g, Tk), -1e30)
-        z = zm.reshape(R, Tk)
+            # is_local is a runtime per-layer flag: a global layer widens
+            # the window past any position difference
+            win = window + (1 - loc_ref[0]) * (1 << 30)
+            ok = ok & (jnp.abs(qp - kp) <= win)
+        z = jnp.where(ok, z, -1e30)
 
-        m_old = m_ref[0]
-        m_new = jnp.maximum(m_old, jnp.max(z, axis=1))
+        m_old = m_sc[...]          # [R, 1]
+        m_new = jnp.maximum(m_old, jnp.max(z, axis=1, keepdims=True))
         alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(z - m_new[:, None])
-        s_ref[0] = s_ref[0] * alpha + jnp.sum(p, axis=1)
-        o_ref[0] = (o_ref[0] * alpha[:, None]
+        p = jnp.exp(z - m_new)
+        s_sc[...] = s_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        o_ref[0] = (o_ref[0] * alpha
                     + jnp.dot(p.astype(v.dtype), v,
                               preferred_element_type=jnp.float32))
-        m_ref[0] = m_new
+        m_sc[...] = m_new
 
     @pl.when(j == n_kv - 1)
     def _final():
-        o_ref[0] = o_ref[0] / jnp.maximum(s_ref[0], 1e-30)[:, None]
+        o_ref[0] = o_ref[0] / jnp.maximum(s_sc[...], 1e-30)
+
+
+def tile_ranges(seg: jax.Array, tile: int) -> jax.Array:
+    """[2, n_tiles] (min, max) segment id of each ``tile``-long stretch."""
+    t = seg.reshape(-1, tile)
+    return jnp.stack([t.min(axis=1), t.max(axis=1)])
+
+
+def _varlen_attention(q, k, v, q_pos, q_seg, kv_pos, kv_seg, kv_valid,
+                      is_local, *, softcap, causal, window, q_tile, kv_tile,
+                      interpret):
+    """Shared pallas_call of the self- and cross-attention entry points.
+    ``kv_pos``/``kv_valid`` are ``[Kp, Tkv]`` with Kp = 1 (shared by every
+    head) or K (per KV head)."""
+    K, RG, dh = q.shape
+    Tq, Tkv = q_pos.shape[0], k.shape[1]
+    g = RG // Tq
+    q_tile = min(q_tile, Tq)
+    kv_tile = min(kv_tile, Tkv)
+    assert Tq % q_tile == 0 and Tkv % kv_tile == 0, (Tq, q_tile, Tkv,
+                                                      kv_tile)
+    n_q, n_kv = Tq // q_tile, Tkv // kv_tile
+    rt = q_tile * g
+    per_head = kv_pos.shape[0] > 1
+
+    def q_rows(x):                 # [Tq] -> [n_q, rt, 1] (GQA rows)
+        return jnp.repeat(x.astype(jnp.int32), g).reshape(n_q, rt, 1)
+
+    def kv_row(x):                 # [Kp, Tkv] -> [Kp, n_kv, 1, kv_tile]
+        return x.astype(jnp.int32).reshape(x.shape[0], n_kv, 1, kv_tile)
+
+    kvh = (lambda h: h) if per_head else (lambda h: 0)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    kern = functools.partial(
+        _kernel, scale=dh ** -0.5, softcap=softcap, causal=causal,
+        window=window, n_kv=n_kv)
+    return pl.pallas_call(
+        kern,
+        grid=(K, n_q, n_kv),
+        in_specs=[
+            smem, smem, smem,
+            pl.BlockSpec((1, rt, dh), lambda h, i, j: (h, i, 0)),
+            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
+            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
+            pl.BlockSpec((1, rt, 1), lambda h, i, j: (i, 0, 0)),
+            pl.BlockSpec((1, rt, 1), lambda h, i, j: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, kv_tile),
+                         lambda h, i, j: (kvh(h), j, 0, 0)),
+            pl.BlockSpec((1, 1, kv_tile), lambda h, i, j: (j, 0, 0)),
+            pl.BlockSpec((1, 1, 1, kv_tile),
+                         lambda h, i, j: (kvh(h), j, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, rt, dh), lambda h, i, j: (h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((K, RG, dh), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rt, 1), jnp.float32),
+                        pltpu.VMEM((rt, 1), jnp.float32)],
+        interpret=interpret,
+    )(tile_ranges(q_seg.astype(jnp.int32), q_tile),
+      tile_ranges(kv_seg.astype(jnp.int32), kv_tile),
+      is_local.astype(jnp.int32).reshape(1),
+      q, k, v, q_rows(q_pos), q_rows(q_seg), kv_row(kv_pos),
+      kv_seg.astype(jnp.int32).reshape(n_kv, 1, kv_tile), kv_row(kv_valid))
 
 
 @functools.partial(JC.jit, static_argnames=(
@@ -103,116 +171,19 @@ def flash_varlen_call(
     kv_valid: jax.Array,  # [T] bool
     is_local: jax.Array,  # [1] bool (gemma2 alternating local layers)
     *,
+    interpret: bool,
     softcap: float = 0.0,
     causal: bool = False,
     window: int = 0,
     q_tile: int = 256,
     kv_tile: int = 512,
-    interpret: bool = True,
 ):
-    K, RG, dh = q.shape
-    T = k.shape[1]
-    g = RG // T
-    q_tile = min(q_tile, T)
-    kv_tile = min(kv_tile, T)
-    assert T % q_tile == 0 and T % kv_tile == 0, (T, q_tile, kv_tile)
-    n_q, n_kv = T // q_tile, T // kv_tile
-    kern = functools.partial(
-        _kernel, scale=dh ** -0.5, softcap=softcap, g=g, causal=causal,
-        window=window, n_kv=n_kv)
-    out, m, s = pl.pallas_call(
-        kern,
-        grid=(K, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, q_tile * g, dh), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((q_tile,), lambda h, i, j: (i,)),
-            pl.BlockSpec((kv_tile,), lambda h, i, j: (j,)),
-            pl.BlockSpec((q_tile,), lambda h, i, j: (i,)),
-            pl.BlockSpec((kv_tile,), lambda h, i, j: (j,)),
-            pl.BlockSpec((kv_tile,), lambda h, i, j: (j,)),
-            pl.BlockSpec((1,), lambda h, i, j: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, q_tile * g, dh), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, q_tile * g), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, q_tile * g), lambda h, i, j: (h, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, RG, dh), jnp.float32),
-            jax.ShapeDtypeStruct((K, RG), jnp.float32),
-            jax.ShapeDtypeStruct((K, RG), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, pos, pos, seg, seg, kv_valid, is_local)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# cross-attention variant: packed block queries vs. per-segment retained KV
-# (the Reuse phase of the whole-iteration packed pipeline)
-# ---------------------------------------------------------------------------
-
-def _cross_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, qseg_ref,
-                  kseg_ref, kvalid_ref, loc_ref, o_ref, m_ref, s_ref,
-                  *, scale: float, softcap: float, g: int, causal: bool,
-                  window: int, n_kv: int):
-    """Like :func:`_kernel` but the query and KV streams are distinct: the
-    queries are the iteration's packed active blocks (``[Tq]``, segment id =
-    reuse-request index) and the KV stream is the per-request ``[retain+Sb]``
-    slice of the slot pool (``[Tkv]``, same segment ids, per-KV-head
-    positions/validity because head-centric selection retains a different
-    token set per head). Both streams are segment-ascending, so the same
-    range-disjointness tile-skip applies: a KV tile owned by other requests
-    never reaches the MXU ("tile-skip over non-owned slots")."""
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        s_ref[...] = jnp.zeros_like(s_ref)
-
-    qs = qseg_ref[...]             # [q_tile]
-    ks = kseg_ref[...]             # [Tk]
-    overlap = (jnp.min(qs) <= jnp.max(ks)) & (jnp.min(ks) <= jnp.max(qs))
-
-    @pl.when(overlap)
-    def _compute():
-        q = q_ref[0]               # [R, dh]  (R = q_tile * G)
-        k = k_ref[0]               # [Tk, dh]
-        v = v_ref[0]
-        qp = qpos_ref[...]         # [q_tile]
-        kp = kpos_ref[0]           # [Tk]   (per KV head)
-        kv = kvalid_ref[0]         # [Tk]   (per KV head)
-
-        z = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if softcap:
-            z = softcap * jnp.tanh(z / softcap)
-        ok = kv[None, :] & (qs[:, None] == ks[None, :])
-        if causal:
-            ok = ok & (qp[:, None] >= kp[None, :])
-        if window:
-            loc = loc_ref[0]
-            ok = ok & ((jnp.abs(qp[:, None] - kp[None, :]) <= window) | ~loc)
-        R, Tk = z.shape
-        zm = jnp.where(ok[:, None, :], z.reshape(R // g, g, Tk), -1e30)
-        z = zm.reshape(R, Tk)
-
-        m_old = m_ref[0]
-        m_new = jnp.maximum(m_old, jnp.max(z, axis=1))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(z - m_new[:, None])
-        s_ref[0] = s_ref[0] * alpha + jnp.sum(p, axis=1)
-        o_ref[0] = (o_ref[0] * alpha[:, None]
-                    + jnp.dot(p.astype(v.dtype), v,
-                              preferred_element_type=jnp.float32))
-        m_ref[0] = m_new
-
-    @pl.when(j == n_kv - 1)
-    def _final():
-        o_ref[0] = o_ref[0] / jnp.maximum(s_ref[0], 1e-30)[:, None]
+    """Segment-masked self-attention over one packed stream; returns the
+    normalized output ``[K, T*G, dh]`` f32."""
+    return _varlen_attention(
+        q, k, v, pos, seg, pos[None], seg, kv_valid[None], is_local,
+        softcap=softcap, causal=causal, window=window, q_tile=q_tile,
+        kv_tile=kv_tile, interpret=interpret)
 
 
 @functools.partial(JC.jit, static_argnames=(
@@ -228,56 +199,26 @@ def flash_varlen_cross_call(
     kv_valid: jax.Array,   # [K, Tkv] bool (False on unselected cache slots)
     is_local: jax.Array,   # [1] bool
     *,
+    interpret: bool,
     softcap: float = 0.0,
     causal: bool = False,
     window: int = 0,
     q_tile: int = 128,
     kv_tile: int = 512,
-    interpret: bool = True,
 ):
     """Ragged cross-attention dispatch (bidirectional dLLM Reuse mask by
     default; ``causal=True`` for the hybrid family's causal shared block).
 
-    Unlike :func:`flash_varlen_call` the query/KV streams differ in length
-    and layout: Tq = Σ block tokens, Tkv = R·(retain + Sb) pool slices. KV
-    positions and validity carry a leading KV-head axis because head-centric
-    selection (C3) retains an independent token set per head.
+    The query stream is the iteration's packed active blocks (Tq = Σ block
+    tokens, segment id = reuse-request index); the KV stream is the
+    per-request ``[retain ; live block]`` slice of the slot pool (Tkv =
+    R·(retain + Sb), same segment ids). KV positions and validity carry a
+    leading KV-head axis because head-centric selection (C3) retains an
+    independent token set per head. Both streams are segment-ascending, so
+    the same tile-skip applies: a KV tile owned by other requests never
+    reaches the MXU.
     """
-    K, RG, dh = q.shape
-    Tq = q_pos.shape[0]
-    Tkv = k.shape[1]
-    g = RG // Tq
-    q_tile = min(q_tile, Tq)
-    kv_tile = min(kv_tile, Tkv)
-    assert Tq % q_tile == 0 and Tkv % kv_tile == 0, (Tq, q_tile, Tkv, kv_tile)
-    n_q, n_kv = Tq // q_tile, Tkv // kv_tile
-    kern = functools.partial(
-        _cross_kernel, scale=dh ** -0.5, softcap=softcap, g=g, causal=causal,
-        window=window, n_kv=n_kv)
-    out, m, s = pl.pallas_call(
-        kern,
-        grid=(K, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, q_tile * g, dh), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((1, kv_tile, dh), lambda h, i, j: (h, j, 0)),
-            pl.BlockSpec((q_tile,), lambda h, i, j: (i,)),
-            pl.BlockSpec((1, kv_tile), lambda h, i, j: (h, j)),
-            pl.BlockSpec((q_tile,), lambda h, i, j: (i,)),
-            pl.BlockSpec((kv_tile,), lambda h, i, j: (j,)),
-            pl.BlockSpec((1, kv_tile), lambda h, i, j: (h, j)),
-            pl.BlockSpec((1,), lambda h, i, j: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, q_tile * g, dh), lambda h, i, j: (h, i, 0)),
-            pl.BlockSpec((1, q_tile * g), lambda h, i, j: (h, i)),
-            pl.BlockSpec((1, q_tile * g), lambda h, i, j: (h, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((K, RG, dh), jnp.float32),
-            jax.ShapeDtypeStruct((K, RG), jnp.float32),
-            jax.ShapeDtypeStruct((K, RG), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v, q_pos, kv_pos, q_seg, kv_seg, kv_valid, is_local)
-    return out
+    return _varlen_attention(
+        q, k, v, q_pos, q_seg, kv_pos, kv_seg, kv_valid, is_local,
+        softcap=softcap, causal=causal, window=window, q_tile=q_tile,
+        kv_tile=kv_tile, interpret=interpret)

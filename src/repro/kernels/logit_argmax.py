@@ -12,9 +12,13 @@ the output stage drops from ``chunk × V × 2B`` (paper) to
 Grid: ``(T // T_tile, V // V_tile)`` — the V axis iterates innermost
 (sequentially on a TPU core), accumulating into revisited output blocks:
 
-  * ``m``   — running max logit           [T]
-  * ``idx`` — running argmax index        [T]
-  * ``s``   — running Σ exp(z − m)        [T]  (online softmax)
+  * ``m``   — running max logit           [T, 1]
+  * ``idx`` — running argmax index        [T, 1]
+  * ``s``   — running Σ exp(z − m)        [T, 1]  (online softmax)
+
+Per-row state is kept as ``[T_tile, 1]`` columns (TPU block rule: a rank-1
+block must equal its array or tile it by the dtype's tiling, which a
+token-bucketed stream cannot promise).
 
 ``conf = 1/s`` (softmax probability of the argmax) is formed in ``ops.py``.
 
@@ -31,13 +35,14 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import jax_compat as JC
 
 
-def _kernel(h_ref, w_ref, valid_ref, idx_ref, m_ref, s_ref, *, softcap: float,
-            v_tile: int, n_v: int, w_layout: str):
-    j = pl.program_id(1)
+def _kernel(any_ref, h_ref, w_ref, idx_ref, m_ref, s_ref, *, softcap: float,
+            v_tile: int, w_layout: str):
+    i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -48,8 +53,9 @@ def _kernel(h_ref, w_ref, valid_ref, idx_ref, m_ref, s_ref, *, softcap: float,
     # whole-iteration packing: the hidden stream is token-bucketed, so a
     # trailing T-tile can be all bucket padding — skip its entire V loop
     # (the matmul never runs; outputs keep their init values and the wrapper
-    # masks them). Within a mixed tile padding rows just ride along.
-    @pl.when(jnp.any(valid_ref[...]))
+    # masks them). Within a mixed tile padding rows just ride along. The
+    # per-tile "any valid row" flags are reduced in XLA and read from SMEM.
+    @pl.when(any_ref[i] != 0)
     def _compute():
         h = h_ref[...]                 # [T_tile, D]
         w = w_ref[...]                 # [D, V_tile] ("dv") | [V_tile, D] ("vd")
@@ -64,13 +70,16 @@ def _kernel(h_ref, w_ref, valid_ref, idx_ref, m_ref, s_ref, *, softcap: float,
         if softcap:
             z = softcap * jnp.tanh(z / softcap)
 
-        local_m = jnp.max(z, axis=1)                           # [T_tile]
-        local_i = jnp.argmax(z, axis=1).astype(jnp.int32) + j * v_tile
+        local_m = jnp.max(z, axis=1, keepdims=True)            # [T_tile, 1]
+        # lowest index attaining the max (argmax's tie-break)
+        col = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+        local_i = jnp.min(jnp.where(z == local_m, col, v_tile), axis=1,
+                          keepdims=True) + j * v_tile
 
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, local_m)
         s_ref[...] = (s_ref[...] * jnp.exp(m_old - m_new)
-                      + jnp.sum(jnp.exp(z - m_new[:, None]), axis=1))
+                      + jnp.sum(jnp.exp(z - m_new), axis=1, keepdims=True))
         idx_ref[...] = jnp.where(local_m > m_old, local_i, idx_ref[...])
         m_ref[...] = m_new
 
@@ -82,12 +91,14 @@ def fused_logit_argmax_call(
     w: jax.Array,          # [D, V] (w_layout="dv") or [V, D] ("vd", tied)
     valid: jax.Array,      # [T] bool (False on bucket-padding rows)
     *,
+    interpret: bool,
     softcap: float = 0.0,
     t_tile: int = 256,
     v_tile: int = 512,
-    interpret: bool = True,
     w_layout: str = "dv",
 ):
+    """Returns (idx [T] i32, m [T] f32, s [T] f32): the argmax, its logit
+    and the softmax denominator relative to it."""
     T, D = h.shape
     V = w.shape[1] if w_layout == "dv" else w.shape[0]
     t_tile = min(t_tile, T)
@@ -95,29 +106,26 @@ def fused_logit_argmax_call(
     assert T % t_tile == 0 and V % v_tile == 0, (T, t_tile, V, v_tile)
     n_t, n_v = T // t_tile, V // v_tile
 
-    kern = functools.partial(_kernel, softcap=softcap, v_tile=v_tile, n_v=n_v,
+    kern = functools.partial(_kernel, softcap=softcap, v_tile=v_tile,
                              w_layout=w_layout)
     w_spec = (pl.BlockSpec((D, v_tile), lambda i, j: (0, j))
               if w_layout == "dv"
               else pl.BlockSpec((v_tile, D), lambda i, j: (j, 0)))
+    row = pl.BlockSpec((t_tile, 1), lambda i, j: (i, 0))
     idx, m, s = pl.pallas_call(
         kern,
         grid=(n_t, n_v),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((t_tile, D), lambda i, j: (i, 0)),
             w_spec,
-            pl.BlockSpec((t_tile,), lambda i, j: (i,)),
         ],
-        out_specs=[
-            pl.BlockSpec((t_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((t_tile,), lambda i, j: (i,)),
-            pl.BlockSpec((t_tile,), lambda i, j: (i,)),
-        ],
+        out_specs=[row, row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((T,), jnp.int32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
-            jax.ShapeDtypeStruct((T,), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1), jnp.int32),
+            jax.ShapeDtypeStruct((T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((T, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(h, w, valid)
-    return idx, m, s
+    )(valid.reshape(n_t, t_tile).any(axis=1).astype(jnp.int32), h, w)
+    return idx[:, 0], m[:, 0], s[:, 0]

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True on CPU (the validation mode required here) and
-False on real TPU backends. Each wrapper adapts the model-layer calling
+:func:`_interpret` is the one place that decides how a kernel runs:
+compiled by Mosaic on a TPU backend, through the Pallas interpreter
+everywhere else (the CPU test mode). No kernel entry point has an
+``interpret`` default of its own. Each wrapper adapts the model-layer calling
 convention ([B, S, H, dh] tensors) to the kernels' head-major packed layout.
 
 Mesh dispatch: every serving hot path consults
@@ -22,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.jax_compat import P
-from repro.kernels import ref
 from repro.kernels.flash_attention import packed_flash_attention_call
 from repro.kernels.logit_argmax import fused_logit_argmax_call
 from repro.kernels.select_pack import head_score_call, head_score_varlen_call
@@ -86,18 +87,16 @@ def fused_logit_argmax(h, w, *, softcap: float = 0.0, vocab_tile: int = 512,
             hp, w, vp, mesh, msize, V, softcap=softcap, t_tile=t_tile,
             vocab_tile=vocab_tile, w_layout=w_layout)
     else:
-        # vocab tile must divide V (all assigned vocabs are 8-divisible);
-        # zero padding would fabricate logit-0 columns, so fall back to ref.
-        vt = vocab_tile
+        # the vocab tile must divide V: zero padding would fabricate
+        # logit-0 columns, and a jnp stand-in would hide that the kernel
+        # never ran — an indivisible vocab is a configuration error
+        vt = min(vocab_tile, V)
         while V % vt:
             vt //= 2
             if vt < 8:
-                wd = w if w_layout == "dv" else w.T
-                ids, conf = ref.fused_logit_argmax(h, wd, softcap=softcap)
-                if valid is not None:
-                    ids = jnp.where(valid, ids, 0)
-                    conf = jnp.where(valid, conf, 0.0)
-                return ids, conf
+                raise ValueError(
+                    f"fused logit argmax: no >=8-column vocab tile divides "
+                    f"the vocab {V}")
         ids, m, s = fused_logit_argmax_call(
             hp, w, vp, softcap=softcap, t_tile=t_tile, v_tile=vt,
             interpret=_interpret(), w_layout=w_layout)

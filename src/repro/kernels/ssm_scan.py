@@ -10,8 +10,13 @@ term as MXU matmuls + an O(1)-state inter-chunk recurrence carried across
 grid steps), so FLOPs scale with real tokens instead of the padded
 ``batch_bucket × max_seq_len`` rectangle.
 
-Grid is 1-D over stream chunks (sequential — the state carry lives in an
-output ref revisited by every step, like the flash kernels' accumulators).
+Grid is ``(H, n_chunks)``: one state head per outer step, the stream's
+chunks sequentially inside it (the head's state carry lives in an output
+block revisited by every chunk step, like the flash kernels'
+accumulators). Every in-kernel value is 2-D, as the TPU's vector unit
+wants: each head's decay cumsums arrive both as a ``[c, 1]`` column and a
+``[1, c]`` row, and ``x·dt`` both as ``[c, P]`` and transposed ``[P, c]``,
+all prepared by XLA outside the kernel.
 Segment resets are handled by a *reset-count* mask, NOT by a −inf decay
 injection: a pair (j → i) contributes iff no reset falls in ``(j, i]``
 (``cnt[i] == cnt[j]`` for the inclusive reset prefix-count), which keeps the
@@ -27,9 +32,9 @@ and accumulates it into the ``[R, H, P, N]`` capture output — no
 jnp associative-scan fallback's memory cost, see
 :func:`repro.models.ssm.varlen_ssd_scan`).
 
-Cumulative sums are computed as lower-triangular matmuls (MXU-friendly; no
-reliance on ``cumsum`` lowering inside the kernel). All exponents are ≤ 0 on
-unmasked lanes (dA = dt·A < 0), so nothing overflows where it matters;
+The in-chunk cumulative sums (of ``dA`` and of the reset flags) are
+computed in XLA before the kernel, in exact f32 adds. All exponents are ≤ 0
+on unmasked lanes (dA = dt·A < 0), so nothing overflows where it matters;
 masked lanes may hit ``inf`` before the ``where`` discards them.
 """
 from __future__ import annotations
@@ -40,88 +45,71 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import jax_compat as JC
 
 
-def _kernel(xdt_ref, dA_ref, b_ref, c_ref, reset_ref, cap_ref,
-            y_ref, cap_out_ref, state_ref, *, c: int, r_cap: int):
-    i = pl.program_id(0)
+def _kernel(cap_ref, x_ref, xt_ref, csc_ref, csr_ref, cntc_ref, cntr_ref,
+            b_ref, c_ref, y_ref, cap_out_ref, state_ref, *, c: int,
+            r_cap: int):
+    i = pl.program_id(1)
 
     @pl.when(i == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
         cap_out_ref[...] = jnp.zeros_like(cap_out_ref)
 
-    xdt = xdt_ref[...]        # [c, H, P] f32  (x · dt)
-    dA = dA_ref[...]          # [c, H]    f32  (dt · A, always < 0)
-    Bm = b_ref[...]           # [c, N]    f32
-    Cm = c_ref[...]           # [c, N]    f32
-    rst = reset_ref[...]      # [c]       f32  (1.0 at segment starts)
-    state_in = state_ref[...]             # [H, P, N] f32
-    H, P = xdt.shape[1], xdt.shape[2]
-    N = Bm.shape[1]
-
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    tri = (ii >= jj).astype(jnp.float32)
-    # inclusive prefix sums via triangular matmul: cs[i] = Σ_{t≤i} dA[t]
-    cs = jnp.dot(tri, dA, preferred_element_type=jnp.float32)        # [c, H]
-    cnt = jnp.dot(tri, rst[:, None],
-                  preferred_element_type=jnp.float32)[:, 0]          # [c]
+    x = x_ref[0]              # [c, P] f32  (x · dt)
+    xt = xt_ref[0, 0]         # [P, c]      (the same, transposed)
+    csc = csc_ref[0]          # [c, 1]      in-chunk inclusive Σ dA
+    csr = csr_ref[0, 0]       # [1, c]
+    cntc = cntc_ref[...]      # [c, 1]      in-chunk inclusive reset count
+    cntr = cntr_ref[0]        # [1, c]
+    Bm = b_ref[...]           # [c, N]
+    Cm = c_ref[...]           # [c, N]
+    state_in = state_ref[0]   # [P, N]
+    nt = (((1,), (1,)), ((), ()))
 
     # 1) intra-chunk quadratic term: (j → i) decays exp(cs_i − cs_j) and is
     # masked out when a reset falls in (j, i] (different inclusive counts)
-    same = cnt[:, None] == cnt[None, :]
-    run_ok = (ii >= jj) & same
-    dec_ij = jnp.exp(cs[:, None, :] - cs[None, :, :])                # [c,c,H]
-    L = jnp.where(run_ok[..., None], dec_ij, 0.0)
-    scores = jnp.dot(Cm, Bm.T, preferred_element_type=jnp.float32)   # [c, c]
-    M = (scores[..., None] * L).transpose(2, 0, 1)                   # [H,c,c]
-    xh = xdt.transpose(1, 0, 2)                                      # [H,c,P]
-    y_diag = jax.lax.dot_general(
-        M, xh, (((2,), (1,)), ((0,), (0,))))                         # [H,c,P]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    L = jnp.where((ii >= jj) & (cntc == cntr), jnp.exp(csc - csr), 0.0)
+    scores = jax.lax.dot_general(Cm, Bm, nt,
+                                 preferred_element_type=jnp.float32)
+    y_diag = jnp.dot(scores * L, x, preferred_element_type=jnp.float32)
 
     # 2) incoming-state term: token i sees the carried state iff no reset ≤ i
-    gate0 = jnp.where(cnt == 0.0, 1.0, 0.0)                          # [c]
-    csx = jnp.exp(cs) * gate0[:, None]                               # [c, H]
-    c_st = jax.lax.dot_general(
-        Cm, state_in, (((1,), (2,)), ((), ())))                      # [c,H,P]
-    y_ref[...] = y_diag.transpose(1, 0, 2) + c_st * csx[..., None]
+    c_st = jax.lax.dot_general(Cm, state_in, nt,
+                               preferred_element_type=jnp.float32)  # [c, P]
+    y_ref[0] = y_diag + c_st * jnp.where(cntc == 0.0, jnp.exp(csc), 0.0)
 
-    # 3) per-request state capture (state AFTER flat row cap_rows[r])
-    cap = cap_ref[...]                                               # [R] i32
-    loc = cap - i * c
-    in_ch = (loc >= 0) & (loc < c)
-    loc_c = jnp.clip(loc, 0, c - 1)
-    rr = jax.lax.broadcasted_iota(jnp.int32, (r_cap, c), 1)
-    onehot = ((rr == loc_c[:, None]) & in_ch[:, None]).astype(jnp.float32)
-    cs_at = jnp.dot(onehot, cs, preferred_element_type=jnp.float32)  # [R, H]
-    cnt_at = jnp.dot(onehot, cnt[:, None],
-                     preferred_element_type=jnp.float32)[:, 0]       # [R]
-    wmask = (rr <= loc_c[:, None]) & in_ch[:, None] \
-        & (cnt[None, :] == cnt_at[:, None])
-    w = jnp.where(wmask[..., None],
-                  jnp.exp(cs_at[:, None, :] - cs[None, :, :]), 0.0)  # [R,c,H]
-    G = xdt[:, :, :, None] * Bm[:, None, None, :]                    # [c,H,P,N]
-    Gh = G.transpose(1, 0, 2, 3).reshape(H, c, P * N)
-    wh = w.transpose(2, 0, 1)                                        # [H,R,c]
-    contrib = jax.lax.dot_general(
-        wh, Gh, (((2,), (1,)), ((0,), (0,))))                        # [H,R,PN]
-    contrib = contrib.reshape(H, r_cap, P, N).transpose(1, 0, 2, 3)
-    basef = jnp.where(in_ch & (cnt_at == 0.0), 1.0, 0.0)             # [R]
-    base = jnp.exp(cs_at) * basef[:, None]                           # [R, H]
-    cap_out_ref[...] += contrib + base[..., None, None] * state_in[None]
+    # 3) per-request state capture (state AFTER flat row cap_rows[r]); only
+    # the chunk that owns row cap_rows[r] contributes
+    jr = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
+    for r in range(r_cap):
+        loc = cap_ref[r] - i * c
+
+        @pl.when((loc >= 0) & (loc < c))
+        def _capture():
+            at = jr == loc
+            cs_at = jnp.sum(jnp.where(at, csr, 0.0), axis=1, keepdims=True)
+            cnt_at = jnp.sum(jnp.where(at, cntr, 0.0), axis=1, keepdims=True)
+            w = jnp.where((jr <= loc) & (cntr == cnt_at),
+                          jnp.exp(cs_at - csr), 0.0)                # [1, c]
+            contrib = jnp.dot(xt * w, Bm,
+                              preferred_element_type=jnp.float32)   # [P, N]
+            base = jnp.where(cnt_at == 0.0, jnp.exp(cs_at), 0.0)    # [1, 1]
+            cap_out_ref[0, r] += contrib + base * state_in
 
     # 4) chunk-end state for the inter-chunk recurrence
-    endg = jnp.where(cnt[-1] == cnt, 1.0, 0.0)                       # [c]
-    dec = jnp.exp(cs[-1][None, :] - cs) * endg[:, None]              # [c, H]
-    dxh = (dec[..., None] * xdt).transpose(1, 2, 0)                  # [H,P,c]
-    delta = jax.lax.dot_general(
-        dxh, Bm, (((2,), (0,)), ((), ())))                           # [H,P,N]
-    keep = jnp.where(cnt[-1] == 0.0, 1.0, 0.0)
-    state_ref[...] = state_in * (jnp.exp(cs[-1]) * keep)[:, None, None] \
-        + delta
+    cs_end = csr[:, c - 1:]                                         # [1, 1]
+    cnt_end = cntr[:, c - 1:]
+    dec = jnp.where(cntr == cnt_end, jnp.exp(cs_end - csr), 0.0)    # [1, c]
+    delta = jnp.dot(xt * dec, Bm, preferred_element_type=jnp.float32)
+    keep = jnp.where(cnt_end == 0.0, jnp.exp(cs_end), 0.0)
+    state_ref[0] = state_in * keep + delta
 
 
 @functools.partial(JC.jit, static_argnames=("chunk", "interpret"))
@@ -133,8 +121,8 @@ def ssm_segment_scan_call(
     reset: jax.Array,     # [T]       f32  1.0 at segment-start tokens
     cap_rows: jax.Array,  # [R]       i32  flat row of each capture (−1: zero)
     *,
+    interpret: bool,
     chunk: int = 64,
-    interpret: bool = True,
 ):
     """Returns (y [T, H, P] f32, captured states [R, H, P, N] f32,
     final state [H, P, N] f32)."""
@@ -142,29 +130,40 @@ def ssm_segment_scan_call(
     N = Bm.shape[1]
     R = cap_rows.shape[0]
     assert T % chunk == 0, (T, chunk)
-    n_chunks = T // chunk
+    n = T // chunk
+    f32 = jnp.float32
+    x_h = xdt.astype(f32).transpose(1, 0, 2)                   # [H, T, P]
+    xt_h = x_h.reshape(H, n, chunk, P).transpose(0, 1, 3, 2)   # [H, n, P, c]
+    cs = jnp.cumsum(dA.astype(f32).T.reshape(H, n, chunk), axis=2)
+    cnt = jnp.cumsum(reset.astype(f32).reshape(n, chunk), axis=1)
     kern = functools.partial(_kernel, c=chunk, r_cap=R)
     y, cap, state = pl.pallas_call(
         kern,
-        grid=(n_chunks,),
+        grid=(H, n),
         in_specs=[
-            pl.BlockSpec((chunk, H, P), lambda i: (i, 0, 0)),
-            pl.BlockSpec((chunk, H), lambda i: (i, 0)),
-            pl.BlockSpec((chunk, N), lambda i: (i, 0)),
-            pl.BlockSpec((chunk, N), lambda i: (i, 0)),
-            pl.BlockSpec((chunk,), lambda i: (i,)),
-            pl.BlockSpec((R,), lambda i: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, chunk, P), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, 1, P, chunk), lambda h, i: (h, i, 0, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda h, i: (h, i, 0, 0)),
+            pl.BlockSpec((chunk, 1), lambda h, i: (i, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda h, i: (i, 0, 0)),
+            pl.BlockSpec((chunk, N), lambda h, i: (i, 0)),
+            pl.BlockSpec((chunk, N), lambda h, i: (i, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((chunk, H, P), lambda i: (i, 0, 0)),
-            pl.BlockSpec((R, H, P, N), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((H, P, N), lambda i: (0, 0, 0)),
+            pl.BlockSpec((1, chunk, P), lambda h, i: (h, i, 0)),
+            pl.BlockSpec((1, R, P, N), lambda h, i: (h, 0, 0, 0)),
+            pl.BlockSpec((1, P, N), lambda h, i: (h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((T, H, P), jnp.float32),
-            jax.ShapeDtypeStruct((R, H, P, N), jnp.float32),
-            jax.ShapeDtypeStruct((H, P, N), jnp.float32),
+            jax.ShapeDtypeStruct((H, T, P), f32),
+            jax.ShapeDtypeStruct((H, R, P, N), f32),
+            jax.ShapeDtypeStruct((H, P, N), f32),
         ],
         interpret=interpret,
-    )(xdt, dA, Bm, Cm, reset, cap_rows)
-    return y, cap, state
+    )(cap_rows.astype(jnp.int32), x_h, xt_h,
+      cs.reshape(H, T, 1), cs.reshape(H, n, 1, chunk),
+      cnt.reshape(T, 1), cnt.reshape(n, 1, chunk),
+      Bm.astype(f32), Cm.astype(f32))
+    return y.transpose(1, 0, 2), cap.transpose(1, 0, 2, 3), state

@@ -2,7 +2,7 @@
 # Host profile for serving runs: wrap any launcher command to get a
 # reproducible host environment (docs/benchmarks.md "Host profile").
 #
-#   src/repro/launch/env.sh python -m repro.launch.serve --arch llada-8b ...
+#   src/repro/launch/env.sh python -m repro.launch.serve --arch llada-8b --hbm-gb 16 ...
 #   REPRO_HOST_DEVICES=4 src/repro/launch/env.sh python -m benchmarks.run ...
 #
 # Everything here is a host-side knob, not a numerics knob: result JSONs

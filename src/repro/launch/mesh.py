@@ -7,6 +7,15 @@ jax device state (required so smoke tests see 1 device while the dry-run sees
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the serving and dry-run
+    paths place arrays by NamedSharding and let GSPMD propagate the rest,
+    which explicit axes (jax's default) reject at the embed gather."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,10 +25,10 @@ def make_production_mesh(*, multi_pod: bool = False):
         dims = tuple(int(x) for x in override.split(","))
         axes = (("pod", "data", "model") if len(dims) == 3
                 else ("data", "model"))
-        return jax.make_mesh(dims, axes)
+        return _make_mesh(dims, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple:
@@ -38,7 +47,7 @@ def axis_size(mesh, name) -> int:
 
 def small_test_mesh(n_data: int = 2, n_model: int = 2):
     """Tiny mesh for CPU subprocess tests (requires host device override)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _make_mesh((n_data, n_model), ("data", "model"))
 
 
 def _axes_for(ndim: int) -> tuple:
@@ -73,7 +82,7 @@ def make_serving_mesh(mesh_shape):
     if not mesh_shape:
         return None
     mesh_shape = tuple(int(d) for d in mesh_shape)
-    return jax.make_mesh(mesh_shape, _axes_for(len(mesh_shape)))
+    return _make_mesh(mesh_shape, _axes_for(len(mesh_shape)))
 
 
 class SimMesh:

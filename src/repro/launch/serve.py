@@ -1,7 +1,7 @@
 """Serving launcher: run the dLLM-Serve engine over a synthetic workload.
 
 Example (CPU, reduced config):
-  PYTHONPATH=src python -m repro.launch.serve --arch llada-8b --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch llada-8b --hbm-gb 16 \
       --system dllm-serve --workload burst --rps 2.0 --n 12
 
 Mesh serving: ``--mesh 1,2`` (or ``REPRO_MESH=1,2`` in the environment) runs
@@ -34,13 +34,28 @@ from repro.data.workloads import make_trace, prefix_share_factor, \
 from repro.launch.mesh import parse_mesh_env
 
 
+def device_memory_bytes() -> int:
+    """Allocatable bytes of the first device, as the runtime reports them
+    (``memory_stats()["bytes_limit"]``). A backend that reports no limit
+    (XLA:CPU) is an error — slot sizing never guesses."""
+    import jax
+    dev = jax.devices()[0]
+    stats = dev.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        raise RuntimeError(
+            f"{dev.platform} device {dev.device_kind!r} reports no memory "
+            f"limit; pass the slot-sizing budget explicitly (hbm_bytes)")
+    return int(stats["bytes_limit"])
+
+
 def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
               use_reduced: bool = True, seed: int = 0,
               max_seq_len: int = 256, block_size: int = 8,
               steps_per_block: int = 8, max_slots: int = 12,
               max_num_batched_tokens: int = 1024, max_num_logits: int = 128,
               time_scale: float = 1.0, length_scale: float = 0.15,
-              size_by_profiler: bool = True, hbm_gb: int = 24,
+              size_by_profiler: bool = True,
+              hbm_bytes: Optional[int] = None,
               clock: str = "modeled", quiet: bool = True,
               mesh_shape: Optional[Tuple[int, ...]] = None,
               queue_cap: int = 0, queue_policy: str = "reject",
@@ -52,9 +67,15 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
               kv_quant: str = "none",
               pipeline: bool = True,
               stream: bool = False) -> dict:
+    """Serve one synthetic trace and return the stats as a dict.
+
+    ``size_by_profiler`` clamps ``max_slots`` to what the offline profiler
+    (§4.2) fits in one device's memory for the config that runs, billed in
+    its own dtype: ``hbm_bytes`` when given (hosts whose runtime reports no
+    memory limit, such as XLA:CPU, must give it), else the device's own
+    ``bytes_limit``. On a mesh the plan is per device."""
     import dataclasses
     cfg = get_config(arch)
-    full_cfg = cfg
     if use_reduced:
         cfg = reduced(cfg)
     base = ServeConfig(
@@ -85,23 +106,15 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
     share = prefix_share_factor(trace) if serve.prefix_sharing else 1.0
     plan = None
     if size_by_profiler:
-        # Offline profiler (§4.2) at FULL-model geometry and paper Table 3
-        # settings decides each system's concurrency: monolithic logit
-        # reservations and dense caches buy fewer KV slots — the paper's
-        # capacity coupling, carried into the (scaled) serving run. The
-        # mesh_shape rides along, so an N-device mesh is sized by its
-        # per-device arithmetic (hbm_gb = one device's HBM). Sharing and
+        # monolithic logit reservations and dense caches buy fewer KV slots
+        # — the paper's capacity coupling. The mesh_shape rides along, so an
+        # N-device mesh is sized by its per-device arithmetic. Sharing and
         # int8 KV lift the plan's capacity (docs/memory.md); the engine's
         # allocation clamps to PHYSICAL capacity (size_slots).
-        plan_serve = dataclasses.replace(
-            serve, max_seq_len=2048, max_num_batched_tokens=4000,
-            max_num_logits=2048, max_slots=max_slots)
-        plan = plan_memory(full_cfg, plan_serve, hbm_gb << 30,
-                           share_factor=share)
-        sized = size_slots(full_cfg, plan_serve, hbm_gb << 30,
-                           share_factor=share)
-        serve = dataclasses.replace(serve,
-                                    max_slots=max(1, sized.max_slots))
+        budget = hbm_bytes if hbm_bytes is not None \
+            else device_memory_bytes()
+        plan = plan_memory(cfg, serve, budget, share_factor=share)
+        serve = size_slots(cfg, serve, budget, share_factor=share)
     faults = FaultPlan.seeded(fault_seed) if fault_seed is not None else None
     stream_cb = None
     if stream:
@@ -236,6 +249,8 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llada-8b")
     ap.add_argument("--system", default="dllm-serve",
@@ -288,6 +303,10 @@ def main():
                     help="print a per-request commit event at each "
                          "iteration's deferred sync (first host-side "
                          "sight of the token values)")
+    ap.add_argument("--hbm-gb", type=float, default=None,
+                    help="slot-sizing budget per device in GiB (default: "
+                         "the device's reported memory limit; required on "
+                         "hosts that report none, such as the CPU)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
     if args.mesh == "env":
@@ -306,7 +325,9 @@ def main():
                     kernels=True if args.kernels else None,
                     prefix_sharing=args.sharing, kv_quant=args.kv_quant,
                     clock=args.clock, pipeline=not args.no_pipeline,
-                    stream=args.stream)
+                    stream=args.stream,
+                    hbm_bytes=None if args.hbm_gb is None
+                    else int(args.hbm_gb * (1 << 30)))
     print(json.dumps(res, indent=2))
     if args.out:
         with open(args.out, "w") as f:

@@ -85,7 +85,7 @@ def _analytic_cache_shapes(cfg, batch, retain):
     against the real ``eval_shape`` tree in
     ``test_cache_specs_match_backbone_cache_structure``)."""
     from repro.core.budgeting import _slot_cache_shapes
-    return _slot_cache_shapes(cfg, ServeConfig(dtype=cfg.dtype), retain,
+    return _slot_cache_shapes(cfg, ServeConfig(), retain,
                               batch=batch)
 
 
@@ -139,7 +139,9 @@ def test_retained_length_fallback_engages_on_mqa():
     rules = Rules(cfg, SimMesh((1, 2)), train=False)
     kv = rules.packed_kv(batch=5, retain=64)      # batch%1==0 -> b over data
     assert tuple(kv.k)[2] is None                 # K replicated
-    assert "model" in tuple(tuple(kv.k)[3] or ()), kv.k   # R sharded
+    r_axes = tuple(kv.k)[3]                       # 'model' or ('model',)
+    r_axes = (r_axes,) if isinstance(r_axes, str) else tuple(r_axes or ())
+    assert "model" in r_axes, kv.k                # R sharded
     kv_odd = rules.packed_kv(batch=5, retain=63)  # 63 % 2 != 0
     assert tuple(kv_odd.k)[3] in (None, ()), kv_odd.k     # replicated
 
