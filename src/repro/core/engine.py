@@ -75,7 +75,7 @@ import itertools
 import math
 import time
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -91,13 +91,41 @@ from repro.core.budgeting import (admission_block_reason, can_pack_tokens,
 from repro.core.faults import FaultError, FaultPlan
 from repro.kernels import flash_varlen as FV
 from repro.kernels import ops as OPS
-from repro.core.kv_pool import KVPool
+from repro.core.kv_pool import SPAN_GATHER, SPAN_WRITE, KVPool
 from repro.core.request import Outcome, Phase, Request, State
 from repro.core.scheduler import make_scheduler
 from repro.launch.mesh import make_serving_mesh
 from repro.models import backbone as BB
 from repro.models import lm_head as LM
 from repro.models import transformer as T
+
+# Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``), one
+# per stretch of engine work; docs/engine.md "Spans" says what each covers.
+# With no profiler active a span costs about a microsecond.
+SPAN_PLAN = "dllm.plan"
+SPAN_DISPATCH = "dllm.dispatch"
+SPAN_REFRESH = "dllm.dispatch.refresh"
+SPAN_REUSE = "dllm.dispatch.reuse"
+SPAN_LOGITS = "dllm.dispatch.logits"
+SPAN_SYNC = "dllm.sync"
+SPAN_WAIT = "dllm.sync.wait"
+SPAN_LAND = "dllm.sync.land"
+SPAN_ARRIVAL = "dllm.arrival_wait"
+SPANS = (SPAN_PLAN, SPAN_DISPATCH, SPAN_REFRESH, SPAN_REUSE, SPAN_LOGITS,
+         SPAN_WRITE, SPAN_GATHER, SPAN_SYNC, SPAN_WAIT, SPAN_LAND,
+         SPAN_ARRIVAL)
+_span = jax.profiler.TraceAnnotation
+
+
+def _spanned(name: str):
+    """Run the decorated method inside the host span ``name``."""
+    def deco(f):
+        @wraps(f)
+        def inner(*args, **kwargs):
+            with _span(name):
+                return f(*args, **kwargs)
+        return inner
+    return deco
 
 
 @dataclass(frozen=True)
@@ -202,8 +230,15 @@ class EngineStats:
     dispatched_ahead: int = 0     # iterations planned with a sync pending
     streamed_events: int = 0      # per-iteration commit events emitted to
     #                               the streaming callback
-    # list when unlimited; the engine swaps in a maxlen deque under
-    # ServeConfig.iter_log_cap (O(1) eviction of the oldest rows)
+    # one row per dispatched iteration. ``t`` is the iteration's ``now``,
+    # taken before it is planned: vtime on the modeled clock, seconds since
+    # run() started on the wall clock. ``plan_s`` / ``fill_s`` are host wall
+    # seconds of planning and of the fills and dispatches; ``sync_s`` is the
+    # wall seconds of its device_get, written at the sync (0.0 until then).
+    # The iteration's commits are stamped on Request (t_first_commit,
+    # t_finished): at dispatch on the modeled clock, at the sync on the wall
+    # clock. A list when unlimited; the engine swaps in a maxlen deque
+    # under ServeConfig.iter_log_cap (O(1) eviction of the oldest rows)
     iter_log: List[dict] = field(default_factory=list)
 
     @property
@@ -276,7 +311,10 @@ class _CommitEntry:
     n_act: int                # positions actually unmasked (stats delta)
     epoch: int                # req.commit_epoch at dispatch
     finished: bool            # this commit completed the request
-    t: float                  # commit timestamp (modeled vtime / wall now)
+    # commit stamp, on the engine's run clock: modeled vtime at dispatch
+    # (the synchronous loop's stamp); on the wall clock None until the sync
+    # sets it to when the values reached the host
+    t: Optional[float]
 
 
 @dataclass
@@ -329,6 +367,9 @@ class Engine:
         self.faults = faults
         self.device = device_model or DeviceModel()
         self.vtime = 0.0
+        # the wall clock's zero and scale (reset by each run())
+        self._run_start = time.perf_counter()
+        self._time_scale = 1.0
         self._n_params = cfg.n_active_params()
         self.mask_id = diffusion.mask_token_id(cfg.vocab_size)
         retain = min(serve.retained_len,
@@ -928,14 +969,15 @@ class Engine:
         of scheduler/stats/vtime mutations is exactly the synchronous
         loop's — bit-identity is by construction, not by luck. With
         ``pipeline=False`` each lap syncs immediately (the oracle)."""
-        start = time.perf_counter()
+        start = self._run_start = time.perf_counter()
+        self._time_scale = time_scale
         pending: Optional[_Pending] = None
         it = 0
         while self.scheduler.has_work and it < max_iters:
             if self.clock == "modeled":
                 now = self.vtime
             else:
-                now = (time.perf_counter() - start) / time_scale
+                now = self._run_clock()
             prep = self._begin_iteration(now)
             if pending is not None:
                 # the plan above was built while the previous dispatch was
@@ -989,7 +1031,8 @@ class Engine:
                 else:
                     wait = nxt * time_scale - (time.perf_counter() - start)
                     if wait > 0:
-                        time.sleep(min(wait, 0.05))
+                        with _span(SPAN_ARRIVAL):
+                            time.sleep(min(wait, 0.05))
             it += 1
         if pending is not None:
             # drain the last in-flight iteration OUTSIDE the loop: a drain
@@ -1065,6 +1108,13 @@ class Engine:
         self._sync_iteration(self._dispatch_iteration(prep))
         return True
 
+    def _run_clock(self) -> float:
+        """Seconds since :meth:`run` started (since construction before the
+        first ``run``), in trace seconds (``time_scale``): the wall clock's
+        ``now`` and its commit stamps."""
+        return (time.perf_counter() - self._run_start) / self._time_scale
+
+    @_spanned(SPAN_PLAN)
     def _begin_iteration(self, now: float) -> _Prepared:
         """Plan one iteration: fault-schedule tick, scheduler plan, packed
         layout. Pure host work — no device dispatch, no host sync — so the
@@ -1108,6 +1158,7 @@ class Engine:
         self.stats.host_plan_s += plan_s
         return _Prepared(now, plan, layout, lifecycle, plan_s)
 
+    @_spanned(SPAN_DISPATCH)
     def _dispatch_iteration(self, prep: _Prepared) -> _Pending:
         """Fill stage buffers and launch every device dispatch for one
         planned iteration, advance the control plane, and return the
@@ -1197,20 +1248,21 @@ class Engine:
                                      for r in hidden_rows], axis=0)
                 return jnp.pad(h, ((0, b - N), (0, 0))) if b != N else h
 
-            if self.serve.varlen_pack:
-                # packed: token-bucket rounding + validity mask threaded into
-                # the decode kernel — no pow2 row bucket
-                b = self._logit_bucket(N)
-                valid = np.zeros((b,), bool)
-                valid[:N] = True
-                ids, conf = self._dispatch(
-                    "decode", lambda: self._decode_packed_fn(b)(
-                        self.params, build_h(b), jnp.asarray(valid)))
-            else:
-                b = _bucket(N, lo=self.serve.block_size)
-                ids, conf = self._dispatch(
-                    "decode", lambda: self._decode_fn(b)(self.params,
-                                                         build_h(b)))
+            with _span(SPAN_LOGITS):
+                if self.serve.varlen_pack:
+                    # packed: token-bucket rounding + validity mask threaded
+                    # into the decode kernel — no pow2 row bucket
+                    b = self._logit_bucket(N)
+                    valid = np.zeros((b,), bool)
+                    valid[:N] = True
+                    ids, conf = self._dispatch(
+                        "decode", lambda: self._decode_packed_fn(b)(
+                            self.params, build_h(b), jnp.asarray(valid)))
+                else:
+                    b = _bucket(N, lo=self.serve.block_size)
+                    ids, conf = self._dispatch(
+                        "decode", lambda: self._decode_fn(b)(self.params,
+                                                             build_h(b)))
             # C1: serial sub-batches serialize on device; monolithic runs one
             # big call (launch amortized, memory unbounded)
             if self.serve.logit_mode == "monolithic":
@@ -1230,9 +1282,11 @@ class Engine:
 
         # control-plane advance at DISPATCH time (value-independent):
         # the scheduler sees this iteration's block completions / finishes
-        # before planning the next one, exactly as in the synchronous loop
+        # before planning the next one, exactly as in the synchronous loop.
+        # The modeled clock stamps commits here; the wall clock stamps them
+        # at the sync, when their values reach the host
         entries = self._advance_control(
-            decoded, self.vtime if self.clock == "modeled" else now)
+            decoded, self.vtime if self.clock == "modeled" else None)
 
         # under iter_log_cap the log is a maxlen deque: appending evicts the
         # oldest row in O(1) — the aggregate counters above carry the
@@ -1253,7 +1307,7 @@ class Engine:
         return _Pending(ids, conf, n_real, entries, log_row)
 
     def _advance_control(self, decoded: List[Request],
-                         t_commit: float) -> List[_CommitEntry]:
+                         t_commit: Optional[float]) -> List[_CommitEntry]:
         """Advance every scheduled request's state machine at dispatch time,
         WITHOUT the committed token values (they are still on device).
 
@@ -1262,7 +1316,8 @@ class Engine:
         never of token values — so block completion, phase transitions,
         FINISHED, and the committed-token stat are all computable here.
         The returned entries carry what :meth:`_sync_iteration` needs to
-        land the values once they arrive."""
+        land the values once they arrive. ``t_commit`` None leaves the
+        commit stamps to the sync."""
         entries: List[_CommitEntry] = []
         for j, r in enumerate(decoded):
             steps_left = self.serve.steps_per_block - r.step_in_block
@@ -1280,6 +1335,7 @@ class Engine:
             entries.append(e)
         return entries
 
+    @_spanned(SPAN_SYNC)
     def _sync_iteration(self, pending: _Pending) -> None:
         """The iteration's SINGLE deferred host sync: pull the decode
         outputs, land each entry's token values into its recorded block —
@@ -1294,27 +1350,38 @@ class Engine:
         t0 = time.perf_counter()
         # one blocking transfer instead of two per-array host syncs —
         # the engine's SINGLE annotated sync point (docs/analysis.md)
-        ids, conf = jax.device_get(  # lint: allow(host-sync)
-            (pending.ids, pending.conf))
+        with _span(SPAN_WAIT):
+            ids, conf = jax.device_get(  # lint: allow(host-sync)
+                (pending.ids, pending.conf))
         sync_s = time.perf_counter() - t0
         self.stats.sync_wait_s += sync_s
         pending.log_row["sync_s"] = sync_s
         Sb = self.serve.block_size
-        for e in pending.entries:
-            if e.req.commit_epoch != e.epoch:
-                continue          # preempted while in flight: values dropped
-            rid = ids[e.row * Sb: (e.row + 1) * Sb]
-            rconf = conf[e.row * Sb: (e.row + 1) * Sb]
-            s = e.block_start
-            newblk = diffusion.commit_tokens(e.req.tokens[s: s + Sb], rid,
-                                             rconf, e.n_commit, self.mask_id)
-            e.req.tokens[s: s + Sb] = newblk
-            if self._stream_cb is not None:
-                self.stats.streamed_events += 1
-                self._stream_cb(dict(
-                    rid=e.req.rid, t=e.t, block_idx=e.block_idx,
-                    n_committed=e.n_act, finished=e.finished,
-                    tokens=np.array(newblk)))
+        # wall clock: every value of this iteration reached the host now
+        t_land = self._run_clock() if self.clock == "wall" else None
+        with _span(SPAN_LAND):
+            for e in pending.entries:
+                if e.req.commit_epoch != e.epoch:
+                    continue      # preempted while in flight: values dropped
+                rid = ids[e.row * Sb: (e.row + 1) * Sb]
+                rconf = conf[e.row * Sb: (e.row + 1) * Sb]
+                s = e.block_start
+                newblk = diffusion.commit_tokens(
+                    e.req.tokens[s: s + Sb], rid, rconf, e.n_commit,
+                    self.mask_id)
+                e.req.tokens[s: s + Sb] = newblk
+                if e.t is None:
+                    e.t = t_land
+                    if e.req.t_first_commit < 0 and e.n_act > 0:
+                        e.req.t_first_commit = t_land
+                    if e.finished:
+                        e.req.t_finished = t_land
+                if self._stream_cb is not None:
+                    self.stats.streamed_events += 1
+                    self._stream_cb(dict(
+                        rid=e.req.rid, t=e.t, block_idx=e.block_idx,
+                        n_committed=e.n_act, finished=e.finished,
+                        tokens=np.array(newblk)))
 
     # ------------------------------------------------------------------
     def _mesh_ctx(self):
@@ -1399,21 +1466,22 @@ class Engine:
         b = _bucket(n)
         S = self.serve.max_seq_len
         F = self._fe_len
-        tokens = np.zeros((b, S), np.int32)
-        valid = np.zeros((b, F + S), bool)
-        bstart = np.zeros((b,), np.int32)
-        fe = np.zeros((b, F, self.cfg.frontend_dim), np.float32) \
-            if F else None
-        for j, r in enumerate(chunk):
-            tokens[j] = r.tokens
-            valid[j, : F + r.total_len] = True
-            bstart[j] = F + r.block_start
-            if F:
-                fe[j] = r.frontend
-        self._check_slots(chunk)
-        out = self._dispatch("refresh", lambda: self._refresh_fn(b)(
-            self.params, jnp.asarray(tokens), jnp.asarray(valid),
-            jnp.asarray(bstart), jnp.asarray(fe) if F else None))
+        with _span(SPAN_REFRESH):
+            tokens = np.zeros((b, S), np.int32)
+            valid = np.zeros((b, F + S), bool)
+            bstart = np.zeros((b,), np.int32)
+            fe = np.zeros((b, F, self.cfg.frontend_dim), np.float32) \
+                if F else None
+            for j, r in enumerate(chunk):
+                tokens[j] = r.tokens
+                valid[j, : F + r.total_len] = True
+                bstart[j] = F + r.block_start
+                if F:
+                    fe[j] = r.frontend
+            self._check_slots(chunk)
+            out = self._dispatch("refresh", lambda: self._refresh_fn(b)(
+                self.params, jnp.asarray(tokens), jnp.asarray(valid),
+                jnp.asarray(bstart), jnp.asarray(fe) if F else None))
         self._pool_write(chunk, out.cache, b - n)
         self.stats.padded_refresh_calls += 1
         self.stats.refresh_tokens_real += sum(r.refresh_len for r in chunk)
@@ -1439,6 +1507,7 @@ class Engine:
         self.stats.refresh_tokens_exec += tp
         return out.block_hidden[:n], tp
 
+    @_spanned(SPAN_REFRESH)
     def _refresh_packed(self, chunk: List[Request], cu_real):
         """Fill one packed Refresh stream for ``chunk`` (segment j starts at
         flat row ``cu_real[j]``) and dispatch it. Returns (RefreshOut,
@@ -1496,6 +1565,7 @@ class Engine:
         out, _, _ = self._refresh_packed(list(reqs), cu)
         return out
 
+    @_spanned(SPAN_REUSE)
     def _run_reuse(self, reqs: List[Request]) -> Tuple[jax.Array, int]:
         """Padded-oracle Reuse: pow2 request bucket, scratch-slot pad rows.
         Returns (block hidden [n, Sb, D], executed tokens = bucket·Sb)."""
@@ -1521,6 +1591,7 @@ class Engine:
         self.stats.reuse_tokens_exec += b * Sb
         return h[:n], b * Sb
 
+    @_spanned(SPAN_REUSE)
     def _run_reuse_packed(self, seg_layout) -> Tuple[jax.Array, int]:
         """Token-packed Reuse: the iteration's active blocks run as one
         ragged ``[R·Sb]`` query stream against their gathered slot caches —

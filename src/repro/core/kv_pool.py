@@ -57,6 +57,11 @@ import numpy as np
 from repro import jax_compat as JC
 from repro.core.share_ledger import ShareLedger
 
+# host spans on the profiler's clock around each pool call (the engine's
+# full list of span names is ``repro.core.engine.SPANS``)
+SPAN_WRITE = "dllm.pool.write"
+SPAN_GATHER = "dllm.pool.gather"
+
 
 class KVPool:
     def __init__(self, max_slots: int, shardings=None,
@@ -291,9 +296,10 @@ class KVPool:
             self._copy_row(self.scratch_slot, self.scratch_slot)
 
     def write(self, slots: Sequence[int], cache) -> None:
-        self.ensure(cache)
-        idx = jnp.asarray(np.asarray(slots, np.int32))
-        self.cache = self._write(self.cache, cache, idx)
+        with jax.profiler.TraceAnnotation(SPAN_WRITE):
+            self.ensure(cache)
+            idx = jnp.asarray(np.asarray(slots, np.int32))
+            self.cache = self._write(self.cache, cache, idx)
 
     def write_shared(self, slots: Sequence[int], cache,
                      keys: Sequence[Optional[bytes]]) -> None:
@@ -307,24 +313,26 @@ class KVPool:
         if self.ledger is None:
             raise RuntimeError("KVPool: write_shared on a pool constructed "
                                "without sharing=True")
-        self.ensure(cache)
-        scatter = list(slots)
-        for j, (s, key) in enumerate(zip(slots, keys)):
-            if key is None or not 0 <= s < self.max_slots:
-                continue
-            do_write, promote = self.ledger.record_write(s, key)
-            if promote is not None:
-                self._copy_row(*promote)
-            if not do_write:
-                scatter[j] = self.scratch_slot
-        idx = jnp.asarray(np.asarray(scatter, np.int32))
-        self.cache = self._write(self.cache, cache, idx)
+        with jax.profiler.TraceAnnotation(SPAN_WRITE):
+            self.ensure(cache)
+            scatter = list(slots)
+            for j, (s, key) in enumerate(zip(slots, keys)):
+                if key is None or not 0 <= s < self.max_slots:
+                    continue
+                do_write, promote = self.ledger.record_write(s, key)
+                if promote is not None:
+                    self._copy_row(*promote)
+                if not do_write:
+                    scatter[j] = self.scratch_slot
+            idx = jnp.asarray(np.asarray(scatter, np.int32))
+            self.cache = self._write(self.cache, cache, idx)
         self.phys_peak = max(self.phys_peak, self.ledger.phys_slots)
 
     def gather(self, slots: Sequence[int]):
-        if self.ledger is not None:
-            # referrers read their owner's row — the one place logical
-            # slots translate to physical rows
-            slots = [self.ledger.resolve(s) for s in slots]
-        idx = jnp.asarray(np.asarray(slots, np.int32))
-        return self._gather(self.cache, idx)
+        with jax.profiler.TraceAnnotation(SPAN_GATHER):
+            if self.ledger is not None:
+                # referrers read their owner's row — the one place logical
+                # slots translate to physical rows
+                slots = [self.ledger.resolve(s) for s in slots]
+            idx = jnp.asarray(np.asarray(slots, np.int32))
+            return self._gather(self.cache, idx)

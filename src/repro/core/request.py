@@ -106,7 +106,9 @@ class Request:
     recomputed_tokens: int = 0           # commits discarded by rollbacks
     outcome: Optional[Outcome] = None    # terminal outcome (None while live)
     error: Optional[str] = None          # per-request error on rejection
-    # metrics
+    # metrics, on the engine's run clock: admission when planned; the first
+    # commit and the finish when their values reach the host (wall clock)
+    # or when dispatched (modeled clock)
     t_admitted: float = -1.0
     t_first_commit: float = -1.0
     t_finished: float = -1.0
@@ -191,7 +193,7 @@ class Request:
     def block_masked(self) -> int:
         return int((self.block_tokens() == self.mask_id).sum())
 
-    def advance_control(self, n_commit: int, now: float) -> int:
+    def advance_control(self, n_commit: int, now: Optional[float]) -> int:
         """Advance the state machine by one committed denoising step WITHOUT
         the committed token values (they may still be in flight on device —
         the pipelined engine calls this at dispatch time and applies the
@@ -203,9 +205,11 @@ class Request:
         deterministic functions of ``n_commit`` and the counters here —
         value-independence is what makes dispatch-ahead bit-identical to
         the synchronous oracle. Returns the number of newly committed
-        positions (the ``committed_tokens`` stat delta)."""
+        positions (the ``committed_tokens`` stat delta). ``now`` stamps
+        ``t_first_commit`` / ``t_finished``; None leaves them to the caller
+        (the wall-clock engine stamps them when the values land)."""
         n_act = min(n_commit, self.masked_left)
-        if self.t_first_commit < 0 and n_act > 0:
+        if now is not None and self.t_first_commit < 0 and n_act > 0:
             self.t_first_commit = now
         self.masked_left -= n_act
         self.steps_done += 1
@@ -219,7 +223,8 @@ class Request:
             if self.block_idx >= self.n_blocks:
                 self.state = State.FINISHED
                 self.outcome = Outcome.FINISHED
-                self.t_finished = now
+                if now is not None:
+                    self.t_finished = now
         return n_act
 
     def advance(self, new_block_tokens: np.ndarray, now: float) -> None:

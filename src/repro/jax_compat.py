@@ -62,7 +62,11 @@ def reset_compile_counts() -> None:
 def _counting(fn, entry: str, counter):
     """Wrap ``fn`` so each *trace* (= jit cache miss = one XLA compilation)
     increments ``counter[entry]`` and the global ledger. The wrapper body
-    only runs while jax traces, so steady-state cached calls cost nothing."""
+    only runs while jax traces, so steady-state cached calls cost nothing.
+
+    The wrapper is named ``entry``, so the compiled program is the module
+    ``jit_<entry>`` in HLO dumps and device traces (a stage's inner ``fn``
+    or a pool ``lambda`` would otherwise all read ``jit_fn``)."""
     import functools
 
     @functools.wraps(fn)
@@ -72,6 +76,7 @@ def _counting(fn, entry: str, counter):
             counter[entry] += 1
         return fn(*args, **kwargs)
 
+    traced.__name__ = traced.__qualname__ = entry
     return traced
 
 
@@ -83,6 +88,7 @@ def jit(fn=None, *, entry=None, counter=None, donate_argnums=(),
     compilation (trace) of the returned function increments the global
     ``compile_counts()`` ledger and, if given, ``counter[entry]`` (any
     Counter-like mapping — the engine passes its per-instance counter).
+    It also names the compiled program: ``jit_<entry>``.
     Without ``entry`` this is a plain ``jax.jit``. Usable as a decorator
     (``@JC.jit`` / ``@functools.partial(JC.jit, static_argnames=...)``).
 

@@ -29,15 +29,11 @@ for _tc in /usr/lib/x86_64-linux-gnu/libtcmalloc_minimal.so.4 \
 done
 
 # --- XLA / jax -------------------------------------------------------------
-# Step markers bracket each dispatched iteration in device traces so the
-# wall-clock mode's overlap_frac can be cross-checked against a profile.
-_xla="--xla_cpu_enable_xprof_traceme=true"
 # CPU repro of an N-device mesh: REPRO_HOST_DEVICES=N splits the host into
 # N XLA devices (the same flag the mesh docs tell you to set by hand).
 if [[ -n "${REPRO_HOST_DEVICES:-}" ]]; then
-  _xla+=" --xla_force_host_platform_device_count=${REPRO_HOST_DEVICES}"
+  export XLA_FLAGS="--xla_force_host_platform_device_count=${REPRO_HOST_DEVICES}${XLA_FLAGS:+ ${XLA_FLAGS}}"
 fi
-export XLA_FLAGS="${_xla}${XLA_FLAGS:+ ${XLA_FLAGS}}"
 
 # Pin default dtypes: fp32/int32 everywhere, no x64 promotion — the modeled
 # clock and the packed layouts assume 32-bit widths, and an ambient

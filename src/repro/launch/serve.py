@@ -149,6 +149,10 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
     # requests have no completion time and must not skew (or zero) the tail
     fin = [r for r in reqs if r.state == State.FINISHED]
     lats = np.array([r.latency for r in fin]) if fin else np.zeros(1)
+    # time to the first committed tokens reaching the host (dispatch time
+    # under the modeled clock), over every request that committed any
+    firsts = [r.t_first_commit - r.arrival for r in reqs
+              if r.t_first_commit >= 0] or [0.0]
     # goodput: tokens of requests that finished BEFORE their deadline —
     # shedding (or blowing deadlines) can't masquerade as throughput
     good_tokens = sum(r.gen_len for r in fin if r.met_deadline)
@@ -173,6 +177,8 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
         avg_latency=float(lats.mean()),
         p50_latency=float(np.percentile(lats, 50)),
         p99_latency=float(np.percentile(lats, 99)),
+        p50_first_commit=float(np.percentile(firsts, 50)),
+        p90_first_commit=float(np.percentile(firsts, 90)),
         latency_std=float(lats.std()),
         tail_span=float(lats.max() - lats.min()),
         refresh_steps=stats.refresh_steps,
