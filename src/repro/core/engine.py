@@ -5,7 +5,8 @@ One engine iteration (§4.1 workflow):
      budget (C2),
   2. Refresh sub-batches run the full-seq forward + head-centric select/pack
      and write packed caches into the slot pool (C3),
-  3. the Reuse set runs active-block attention over gathered slot caches,
+  3. the Reuse set runs active-block attention over its slot caches (read
+     in place from the pool on the attention families' kernel path),
   4. all block hidden states are decoded through the *budgeted* logit stage
      (C1: serial ``max_num_logits`` sub-batches / fused Pallas kernel),
   5. commits are applied host-side and request state machines advance.
@@ -37,9 +38,10 @@ Static-shape policy: two execution paths for the WHOLE iteration.
       are ``[frontend prefix ; text]``; Reuse and the logit stage address
       only the text region (block rows), so prefixes never enter them.
     - Reuse: the iteration's R active blocks form one ragged ``[R·Sb]``
-      query stream (R rounded only to the token-bucket granularity) against
-      their gathered slot caches — the cross-attention varlen kernel skips
-      KV tiles of non-owned slots.
+      query stream (R rounded only to the token-bucket granularity). The
+      attention families' kernel reads each request's retained K/V in place
+      from the slot pool through a slot table (:meth:`_reuse_reads_pool`);
+      the other caches are gathered first (docs/engine.md).
     - Logit stage: the real ``N`` hidden rows are decoded at token-bucket
       granularity with a validity mask threaded into the fused Pallas argmax
       kernel; all-padding chunks are never paid for.
@@ -98,6 +100,7 @@ from repro.launch.mesh import make_serving_mesh
 from repro.models import backbone as BB
 from repro.models import lm_head as LM
 from repro.models import transformer as T
+from repro.models.sparse_select import PackedKV
 
 # Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``), one
 # per stretch of engine work; docs/engine.md "Spans" says what each covers.
@@ -185,6 +188,8 @@ class EngineStats:
     padded_refresh_calls: int = 0
     packed_reuse_calls: int = 0
     padded_reuse_calls: int = 0
+    reuse_inplace_calls: int = 0  # packed Reuse calls that read the slot
+    #                               pool in place (no gather)
     # -- request lifecycle / robustness accounting (docs/robustness.md) ----
     # Conservation law (asserted by the chaos suite): every submitted
     # request reaches exactly one terminal outcome —
@@ -237,8 +242,10 @@ class EngineStats:
     # wall seconds of its device_get, written at the sync (0.0 until then).
     # The iteration's commits are stamped on Request (t_first_commit,
     # t_finished): at dispatch on the modeled clock, at the sync on the wall
-    # clock. A list when unlimited; the engine swaps in a maxlen deque
-    # under ServeConfig.iter_log_cap (O(1) eviction of the oldest rows)
+    # clock. ``reuse_gathered_slots`` counts the slots the iteration's
+    # Reuse copied out of the pool (0 when it read the pool in place, or
+    # ran no Reuse). A list when unlimited; the engine swaps in a maxlen
+    # deque under ServeConfig.iter_log_cap (O(1) eviction of the oldest rows)
     iter_log: List[dict] = field(default_factory=list)
 
     @property
@@ -671,22 +678,51 @@ class Engine:
                 entry="reuse", counter=self._compile_counter)
         return self._reuse_jit[n]
 
+    def _reuse_reads_pool(self) -> bool:
+        """Whether packed Reuse reads the retained K/V in place from the
+        slot pool through a slot table, instead of gathering the slots
+        first. Decided from what the pool holds: a plain ``PackedKV`` (the
+        attention families; not the hybrid or SSM caches, nor the int8
+        view) on the kernel path, with the slot axis on no more than one
+        device (a slot axis split over ``data`` keeps each replica's slots
+        on its own devices, and the gather is what brings them into the
+        stream layout)."""
+        pool = self.pool
+        return (self.ctx.use_flash_kernel and pool.kv_quant == "none"
+                and isinstance(pool.cache, PackedKV)
+                and (pool.shardings is None
+                     or pool.shardings.k.shard_shape(pool.cache.k.shape)[1]
+                     == pool.cache.k.shape[1]))
+
     def _reuse_packed_fn(self, rp: int):
         if rp not in self._reuse_packed_jit:
             ctx = self.ctx
+            if self._reuse_reads_pool():
+                def fn(params, flat_tokens, flat_positions, pool, rows,
+                       n_live):
+                    return BB.serve_reuse_packed(
+                        params, self.cfg, flat_tokens, flat_positions, pool,
+                        ctx, rows=rows, n_live=n_live)
 
-            def fn(params, flat_tokens, flat_positions, cache):
-                # same KV-load dequant as the padded oracle — here it fuses
-                # into the varlen cross-attention kernel's program
-                cache = OPS.dequantize_gathered(cache, self.serve.kv_quant,
-                                                self.pool.gathered_dtypes)
-                return BB.serve_reuse_packed(params, self.cfg, flat_tokens,
-                                             flat_positions, cache, ctx)
+                # the pool is read in place and lives on: never donated
+                in_specs = None if self.mesh is None else (
+                    self._pspecs, P(), P(), self._pool_spec, P(), P())
+                donate = self._donate(1, 2)
+            else:
+                def fn(params, flat_tokens, flat_positions, cache):
+                    # same KV-load dequant as the padded oracle — here it
+                    # fuses into the varlen cross-attention kernel's program
+                    cache = OPS.dequantize_gathered(
+                        cache, self.serve.kv_quant, self.pool.gathered_dtypes)
+                    return BB.serve_reuse_packed(
+                        params, self.cfg, flat_tokens, flat_positions, cache,
+                        ctx)
 
-            in_specs = self._stage_specs(2, with_cache=True)
+                in_specs = self._stage_specs(2, with_cache=True)
+                donate = self._donate(1, 2, 3)
             self._reuse_packed_jit[rp] = JC.jit_sharded(
                 fn, mesh=self.mesh, in_specs=in_specs,
-                donate_argnums=self._donate(1, 2, 3),
+                donate_argnums=donate,
                 entry="reuse_packed", counter=self._compile_counter)
         return self._reuse_packed_jit[rp]
 
@@ -833,10 +869,10 @@ class Engine:
             # (doubling warm; intermediate multiples compile lazily)
             rp = self._reuse_bucket(1)
             while True:
-                cache = self.pool.gather([self.pool.scratch_slot] * rp)
-                self._reuse_packed_fn(rp)(
-                    self.params, jnp.zeros((rp * Sb,), jnp.int32),
-                    jnp.zeros((rp * Sb,), jnp.int32), cache)
+                self._reuse_packed_call(
+                    rp, [self.pool.scratch_slot] * rp, 0,
+                    np.zeros((rp * Sb,), np.int32),
+                    np.zeros((rp * Sb,), np.int32))
                 if rp >= self._reuse_bucket(r_cap):
                     break
                 rp = min(rp * 2, self._reuse_bucket(r_cap))
@@ -1218,13 +1254,17 @@ class Engine:
                              actual_tokens=t_real)
 
         # ---- Reuse: one ragged block stream (packed) / pow2 batch (oracle) --
-        r_real = r_exec = 0
+        r_real = r_exec = r_gathered = 0
         if plan.reuse:
             r_real = len(plan.reuse) * self.serve.block_size
             if self._use_packed:
                 bh, r_exec = self._run_reuse_packed(layout.reuse)
             else:
                 bh, r_exec = self._run_reuse(plan.reuse)
+            # slots copied out of the pool: every request row's, padding
+            # included, unless the Reuse read the pool in place
+            if not (self._use_packed and self._reuse_reads_pool()):
+                r_gathered = r_exec // self.serve.block_size
             hidden_rows.append(bh)
             decoded.extend(plan.reuse)
             self.stats.reuse_steps += len(plan.reuse)
@@ -1301,6 +1341,7 @@ class Engine:
             n_logits=len(decoded) * self.serve.block_size,
             refresh_tokens_real=iter_real, refresh_tokens_exec=iter_exec,
             reuse_tokens_real=r_real, reuse_tokens_exec=r_exec,
+            reuse_gathered_slots=r_gathered,
             logit_tokens_real=n_real, logit_tokens_exec=n_exec,
             plan_s=prep.plan_s, fill_s=fill_s, sync_s=0.0)
         self.stats.iter_log.append(log_row)
@@ -1554,6 +1595,21 @@ class Engine:
             jnp.asarray(fe) if F else None))
         return out, tp, rp
 
+    def _reuse_packed_call(self, rp: int, slots: List[int], n: int, btok,
+                           bpos) -> jax.Array:
+        """One packed Reuse program call over ``slots`` (the first ``n``
+        real). Everything it passes is read when it is called, inside the
+        dispatch thunk: a fault-retried attempt must see the pool a later
+        ``pool_write`` left (it donates the old buffer), and a gathered
+        cache is donated to the program, so each attempt gathers anew."""
+        fn = self._reuse_packed_fn(rp)
+        if self._reuse_reads_pool():
+            return fn(self.params, jnp.asarray(btok), jnp.asarray(bpos),
+                      self.pool.cache, jnp.asarray(self.pool.rows(slots)),
+                      jnp.asarray([n], jnp.int32))
+        return fn(self.params, jnp.asarray(btok), jnp.asarray(bpos),
+                  self.pool.gather(slots))
+
     def refresh_outputs(self, reqs: List[Request]):
         """The packed Refresh stage over ``reqs`` as one stream, through the
         engine's own stage jit — what an iteration that refreshes exactly
@@ -1594,10 +1650,10 @@ class Engine:
     @_spanned(SPAN_REUSE)
     def _run_reuse_packed(self, seg_layout) -> Tuple[jax.Array, int]:
         """Token-packed Reuse: the iteration's active blocks run as one
-        ragged ``[R·Sb]`` query stream against their gathered slot caches —
-        R is rounded only to the token-bucket granularity (scratch slots
-        back the padding segments), never a pow2 batch bucket. Returns
-        (block hidden [n, Sb, D], executed tokens = rp·Sb)."""
+        ragged ``[R·Sb]`` query stream against their slot caches — R is
+        rounded only to the token-bucket granularity (scratch slots back
+        the padding segments), never a pow2 batch bucket. Returns (block
+        hidden [n, Sb, D], executed tokens = rp·Sb)."""
         reqs = seg_layout.requests
         n = len(reqs)
         Sb = self.serve.block_size
@@ -1614,11 +1670,11 @@ class Engine:
                                             F + r.block_start + Sb)
             slots[j] = r.slot
         self._check_slots(list(reqs))
-        # gather INSIDE the thunk (donated cache; see _run_reuse)
-        h = self._dispatch("reuse", lambda: self._reuse_packed_fn(rp)(
-            self.params, jnp.asarray(btok), jnp.asarray(bpos),
-            self.pool.gather(slots)))
+        h = self._dispatch("reuse", lambda: self._reuse_packed_call(
+            rp, slots, n, btok, bpos))
         self.stats.packed_reuse_calls += 1
+        if self._reuse_reads_pool():
+            self.stats.reuse_inplace_calls += 1
         self.stats.reuse_tokens_real += n * Sb
         self.stats.reuse_tokens_exec += tq
         return h.reshape(rp, Sb, -1)[:n], tq

@@ -3,9 +3,14 @@ Contiguous Storage").
 
 Holds one device-resident cache pytree whose second axis is the request slot
 (``[L, slots+1, ...]``; the extra slot is scratch for padded batch rows).
-Refresh writes a freshly packed cache into a request's slot; Reuse gathers
-slot slices for the scheduled sub-batch. The cache content is family-specific
-(PackedKV / SSMCache / HybridCache) — the pool is shape-agnostic.
+Refresh writes a freshly packed cache into a request's slot. Reuse reads
+it back in one of two ways: the attention families' packed kernel path
+reads the retained K/V in place, through a slot table of :meth:`rows`
+(nothing is copied); the other paths (hybrid and SSM caches, the int8
+view, a pool whose slot axis is sharded over ``data``, the padded oracle)
+copy the scheduled slots out with :meth:`gather`. The cache content is
+family-specific (PackedKV / SSMCache / HybridCache) — the pool is
+shape-agnostic.
 
 Mesh serving: the engine passes the pool a ``NamedSharding`` pytree built
 from ``launch.sharding.Rules.cache`` (KV heads over the ``model`` axis when
@@ -30,7 +35,7 @@ Content-addressed sharing (``sharing=True``, docs/memory.md): a
 and physical rows. :meth:`write_shared` hashes nothing itself — the caller
 supplies each request's content key — but redirects a write whose key is
 already resident to the scratch row (skip) and records the logical slot as
-a referrer of the owning row; :meth:`gather` resolves referrers to their
+a referrer of the owning row; :meth:`rows` resolves referrers to their
 owner row; :meth:`free` releases references, promoting owned bytes to a
 surviving referrer (one device row-copy, the ``pool_copy`` jit) before the
 row is recycled — copy-on-write in both the divergent-Refresh and the
@@ -328,11 +333,15 @@ class KVPool:
             self.cache = self._write(self.cache, cache, idx)
         self.phys_peak = max(self.phys_peak, self.ledger.phys_slots)
 
+    def rows(self, slots: Sequence[int]) -> np.ndarray:
+        """Physical rows of logical ``slots`` ([n] int32): with sharing a
+        referrer reads its owner's row — the one place logical slots
+        translate to physical rows. The slot table of a Reuse that reads
+        the pool in place, and the index of :meth:`gather`."""
+        if self.ledger is not None:
+            slots = [self.ledger.resolve(s) for s in slots]
+        return np.asarray(slots, np.int32)
+
     def gather(self, slots: Sequence[int]):
         with jax.profiler.TraceAnnotation(SPAN_GATHER):
-            if self.ledger is not None:
-                # referrers read their owner's row — the one place logical
-                # slots translate to physical rows
-                slots = [self.ledger.resolve(s) for s in slots]
-            idx = jnp.asarray(np.asarray(slots, np.int32))
-            return self._gather(self.cache, idx)
+            return self._gather(self.cache, jnp.asarray(self.rows(slots)))

@@ -25,8 +25,12 @@ follow the TPU block rules: query-side arrays are ``[n_q, rows, 1]``
 columns, KV-side arrays ``[..., n_kv, 1, kv_tile]`` rows, so every block's
 last two dims equal the array's.
 
-The cross-attention variant (the Reuse phase) runs the same kernel with
-distinct query/KV streams and per-KV-head KV positions/validity.
+The cross-attention variant runs the same kernel with distinct query/KV
+streams and per-KV-head KV positions/validity (the hybrid family's Reuse
+over a gathered cache). The pool variant (``flash_varlen_pool_call``, the
+attention families' Reuse) shares its mask and online-softmax update but
+reads each request's retained K/V in place from the slot pool through a
+scalar-prefetched slot table, with the live block's K/V as a second input.
 """
 from __future__ import annotations
 
@@ -43,6 +47,39 @@ from repro import jax_compat as JC
 # Segment id for bucket-padding tokens. Must sort after every real request id
 # so the ascending-stream tile-skip stays valid.
 PAD_SEG = (1 << 30)
+
+
+def _position_mask(ok, qp, kp, loc, *, causal: bool, window: int):
+    """Narrow ``ok`` by the causal and sliding-window masks of query
+    positions ``qp`` ([R, 1]) against key positions ``kp`` ([1, Tk])."""
+    if causal:
+        ok = ok & (qp >= kp)
+    if window:
+        # is_local is a runtime per-layer flag: a global layer widens the
+        # window past any position difference
+        win = window + (1 - loc) * (1 << 30)
+        ok = ok & (jnp.abs(qp - kp) <= win)
+    return ok
+
+
+def _online_update(q, k, v, ok, o, m_sc, s_sc, *, scale: float,
+                   softcap: float):
+    """One flash online-softmax step: fold the keys ``k`` / values ``v``
+    that ``ok`` allows into query rows ``q``'s running (o, max, sum). The
+    max and sum live in VMEM scratch; returns the new unnormalized o."""
+    z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if softcap:
+        z = softcap * jnp.tanh(z / softcap)
+    z = jnp.where(ok, z, -1e30)
+    m_old = m_sc[...]          # [R, 1]
+    m_new = jnp.maximum(m_old, jnp.max(z, axis=1, keepdims=True))
+    alpha = jnp.exp(m_old - m_new)
+    p = jnp.exp(z - m_new)
+    s_sc[...] = s_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    m_sc[...] = m_new
+    return o * alpha + jnp.dot(p.astype(v.dtype), v,
+                               preferred_element_type=jnp.float32)
 
 
 def _kernel(qrng_ref, krng_ref, loc_ref, q_ref, k_ref, v_ref, qpos_ref,
@@ -64,34 +101,14 @@ def _kernel(qrng_ref, krng_ref, loc_ref, q_ref, k_ref, v_ref, qpos_ref,
 
     @pl.when(overlap)
     def _compute():
-        q = q_ref[0]               # [R, dh]  (R = q_tile * G rows)
-        k = k_ref[0]               # [Tk, dh]
-        v = v_ref[0]
-        z = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if softcap:
-            z = softcap * jnp.tanh(z / softcap)
-        qp = qpos_ref[0]           # [R, 1]
+        qp = qpos_ref[0]           # [R, 1]  (R = q_tile * G rows)
         kp = kpos_ref[0, 0]        # [1, Tk]
         ok = (qseg_ref[0] == kseg_ref[0]) & (kvalid_ref[0, 0] != 0)
-        if causal:
-            ok = ok & (qp >= kp)
-        if window:
-            # is_local is a runtime per-layer flag: a global layer widens
-            # the window past any position difference
-            win = window + (1 - loc_ref[0]) * (1 << 30)
-            ok = ok & (jnp.abs(qp - kp) <= win)
-        z = jnp.where(ok, z, -1e30)
-
-        m_old = m_sc[...]          # [R, 1]
-        m_new = jnp.maximum(m_old, jnp.max(z, axis=1, keepdims=True))
-        alpha = jnp.exp(m_old - m_new)
-        p = jnp.exp(z - m_new)
-        s_sc[...] = s_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        o_ref[0] = (o_ref[0] * alpha
-                    + jnp.dot(p.astype(v.dtype), v,
-                              preferred_element_type=jnp.float32))
-        m_sc[...] = m_new
+        ok = _position_mask(ok, qp, kp, loc_ref[0], causal=causal,
+                            window=window)
+        o_ref[0] = _online_update(q_ref[0], k_ref[0], v_ref[0], ok,
+                                  o_ref[0], m_sc, s_sc, scale=scale,
+                                  softcap=softcap)
 
     @pl.when(j == n_kv - 1)
     def _final():
@@ -222,3 +239,134 @@ def flash_varlen_cross_call(
         q, k, v, q_pos, q_seg, kv_pos, kv_seg, kv_valid, is_local,
         softcap=softcap, causal=causal, window=window, q_tile=q_tile,
         kv_tile=kv_tile, interpret=interpret)
+
+
+def _pool_kernel(rows_ref, live_ref, layer_ref, loc_ref, q_ref, kb_ref,
+                 vb_ref, qpos_ref, bpos_ref, k_ref, v_ref, kpos_ref, o_ref,
+                 m_sc, s_sc, *, scale: float, softcap: float, window: int,
+                 n_c: int):
+    r, c = pl.program_id(0), pl.program_id(2)
+    live = r < live_ref[0]
+    qp = qpos_ref[...]             # [rows, 1]
+    mask = functools.partial(_position_mask, qp=qp, loc=loc_ref[0],
+                             causal=False, window=window)
+    update = functools.partial(_online_update, scale=scale, softcap=softcap)
+
+    @pl.when(c == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
+        s_sc[...] = jnp.zeros_like(s_sc)
+
+    @pl.when(live & (c == 0))
+    def _block():
+        # the live block first: every query sees its own key, so the running
+        # max is finite before a retained tile can be wholly masked
+        bp = bpos_ref[...]         # [1, Sb]
+        o_ref[...] = update(q_ref[...], kb_ref[...], vb_ref[...],
+                            mask(bp >= 0, kp=bp),  # every live key is valid
+                            o_ref[...], m_sc, s_sc)
+
+    @pl.when(live)
+    def _retained():
+        kp = kpos_ref[...]         # [1, ct], -1 where nothing is retained
+        o_ref[...] = update(q_ref[...], k_ref[...], v_ref[...],
+                            mask(kp >= 0, kp=kp), o_ref[...], m_sc, s_sc)
+
+    @pl.when(c == n_c - 1)
+    def _final():
+        o_ref[...] = o_ref[...] / jnp.maximum(s_sc[...], 1e-30)
+
+
+@functools.partial(JC.jit, static_argnames=("softcap", "window", "interpret"))
+def flash_varlen_pool_call(
+    q: jax.Array,          # [K, R*Sb*G, dh] row-flat GQA layout (token-major)
+    k_blk: jax.Array,      # [K, R*Sb, dh] the live blocks' keys
+    v_blk: jax.Array,      # [K, R*Sb, dh]
+    q_pos: jax.Array,      # [R*Sb] int32 absolute position of each query
+    pool_k: jax.Array,     # [L, S, K, Cr, dh] the slot pool's whole leaf
+    pool_v: jax.Array,     # [L, S, K, Cr, dh]
+    kv_pos: jax.Array,     # [L, R, K, n_c, 1, ct] int32 (retained_positions)
+    rows: jax.Array,       # [R] int32 pool row of request r
+    n_live: jax.Array,     # [1] int32 requests [0, n_live) are real
+    layer: jax.Array,      # [1] int32 layer index into the pool's [L] axis
+    is_local: jax.Array,   # [1] bool
+    *,
+    interpret: bool,
+    softcap: float = 0.0,
+    window: int = 0,
+):
+    """Bidirectional packed-Reuse cross attention that reads each request's
+    retained K/V in place from the slot pool through the slot table
+    ``rows``.
+
+    Grid ``(R, K, n_c)``: request r's ``Sb·G`` query rows of head h attend
+    to their own live block (``k_blk``/``v_blk``, folded first) and then to
+    the ``n_c`` retained tiles of pool row ``rows[r]`` in ``layer``, which
+    the BlockSpec index maps fetch straight from HBM. Nothing is gathered
+    or concatenated. Requests at or past ``n_live`` are padding: every
+    index map points them at the last real request's final blocks, so the
+    pipeline issues no DMA for them, and the kernel does no matmul; their
+    output rows are zero. Returns the normalized output ``[K, R*Sb*G, dh]``
+    f32.
+    """
+    K, RG, dh = q.shape
+    R = rows.shape[0]
+    rq, Sb = RG // R, k_blk.shape[1] // R
+    n_c, ct = kv_pos.shape[3], kv_pos.shape[5]
+
+    def own(r, h, c, live_ref):
+        """Padding requests re-read the last real request's last blocks."""
+        live = r < live_ref[0]
+        last = jnp.maximum(live_ref[0] - 1, 0)
+        return (jnp.where(live, r, last), jnp.where(live, h, K - 1),
+                jnp.where(live, c, n_c - 1))
+
+    def pool_map(r, h, c, rows_ref, live_ref, layer_ref, loc_ref):
+        r, h, c = own(r, h, c, live_ref)
+        return layer_ref[0], rows_ref[r], h, c, 0
+
+    def pos_map(r, h, c, rows_ref, live_ref, layer_ref, loc_ref):
+        r, h, c = own(r, h, c, live_ref)
+        return layer_ref[0], r, h, c, 0, 0
+
+    def head_map(r, h, c, rows_ref, live_ref, layer_ref, loc_ref):
+        r, h, _ = own(r, h, c, live_ref)
+        return h, r, 0
+
+    def row_map(r, h, c, rows_ref, live_ref, layer_ref, loc_ref):
+        r, _, _ = own(r, h, c, live_ref)
+        return r, 0, 0
+
+    kern = functools.partial(
+        _pool_kernel, scale=dh ** -0.5, softcap=softcap, window=window,
+        n_c=n_c)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(R, K, n_c),
+        in_specs=[
+            pl.BlockSpec((None, rq, dh), head_map),
+            pl.BlockSpec((None, Sb, dh), head_map),
+            pl.BlockSpec((None, Sb, dh), head_map),
+            pl.BlockSpec((None, rq, 1), row_map),
+            pl.BlockSpec((None, 1, Sb), row_map),
+            pl.BlockSpec((None, None, None, ct, dh), pool_map),
+            pl.BlockSpec((None, None, None, ct, dh), pool_map),
+            pl.BlockSpec((None, None, None, None, 1, ct), pos_map),
+        ],
+        out_specs=pl.BlockSpec((None, rq, dh),
+                               lambda r, h, c, *_: (h, r, 0)),
+        scratch_shapes=[pltpu.VMEM((rq, 1), jnp.float32),
+                        pltpu.VMEM((rq, 1), jnp.float32)],
+    )
+    g = rq // Sb
+    q_rows = jnp.repeat(q_pos.astype(jnp.int32), g).reshape(R, rq, 1)
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((K, RG, dh), jnp.float32),
+        interpret=interpret,
+    )(rows.astype(jnp.int32), n_live.astype(jnp.int32),
+      layer.astype(jnp.int32), is_local.astype(jnp.int32).reshape(1),
+      q, k_blk, v_blk, q_rows, q_pos.astype(jnp.int32).reshape(R, 1, Sb),
+      pool_k, pool_v, kv_pos)

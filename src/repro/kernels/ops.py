@@ -339,7 +339,9 @@ def flash_varlen_cross_attention(q, k, v, *, q_seg, q_pos, kv_seg, kv_pos,
     q_seg/q_pos: [Tq] int32; kv_seg: [Tkv] int32; kv_pos/kv_valid: [K, Tkv]
     (head-centric selection retains different tokens per KV head). Returns
     [Tq, H, dh]. One flat dispatch replaces the pow2-bucketed [B, Sb] Reuse
-    batch; non-owned KV tiles are skipped in-kernel.
+    batch; non-owned KV tiles are skipped in-kernel. The hybrid family's
+    Reuse runs it over a gathered cache; the attention families read the
+    pool in place (:func:`flash_varlen_pool_attention`).
     """
     from repro.kernels.flash_varlen import flash_varlen_cross_call
 
@@ -386,6 +388,88 @@ def flash_varlen_cross_attention(q, k, v, *, q_seg, q_pos, kv_seg, kv_pos,
         out_specs=P(None, "model", None),
         check_vma=False,
     )(q, k, v, q_pos, kv_pos, q_seg, kv_seg, kv_valid, loc)
+
+
+def _pool_tile(Cr: int, kv_tile: int) -> int:
+    """Retained-axis tile of the pool kernel: the whole axis when it fits
+    ``kv_tile``, else its largest divisor that is a multiple of 16 and fits;
+    the whole axis when none does."""
+    if Cr <= kv_tile:
+        return Cr
+    return max((d for d in range(16, kv_tile + 1, 16) if Cr % d == 0),
+               default=Cr)
+
+
+def retained_positions(pos, valid, rows, *, kv_tile: int = 1024):
+    """The pool kernel's view of the slot table's retained positions.
+
+    pos/valid: ``[L, S, K, Cr]`` pool (or gathered) leaves; rows: ``[R]``
+    int32 slot table. Returns ``[L, R, K, n_c, 1, ct]`` int32, -1 where a
+    row retains nothing, laid out so that one ``(1, ct)`` block is a whole
+    tile. The leaves are gathered for the table's rows (a Mosaic operand
+    cannot be bool, and ``(1, Cr)`` rows of a ``[.., K, Cr]`` leaf break the
+    TPU block rule); at 4 or 5 bytes a position this is ~1% of the K/V the
+    kernel reads in place. Built once per program, outside the layer scan.
+    """
+    L, _, K, Cr = pos.shape
+    ct = _pool_tile(Cr, kv_tile)
+    kp = jnp.where(valid[:, rows], pos[:, rows], -1).astype(jnp.int32)
+    return kp.reshape(L, rows.shape[0], K, Cr // ct, 1, ct)
+
+
+def flash_varlen_pool_attention(q, k_blk, v_blk, pool_k, pool_v, kv_pos, *,
+                                rows, n_live, layer, q_pos, window: int = 0,
+                                is_local=False, softcap: float = 0.0):
+    """Bidirectional packed-Reuse cross attention over the slot pool, in
+    place (model contract).
+
+    q: [R·Sb, H, dh] packed block queries; k_blk/v_blk: [R·Sb, K, dh] the
+    live blocks' K/V; pool_k/pool_v: [L, S, K, Cr, dh] whole pool leaves;
+    kv_pos: :func:`retained_positions` of the slot table ``rows`` ([R]
+    int32); n_live: [1] int32, the leading real requests; layer: the layer
+    index into the pool's [L] axis; q_pos: [R·Sb]. Returns [R·Sb, H, dh].
+    Under a model axis each shard reads its own KV heads of the
+    head-sharded pool (``Rules.cache``): nothing is gathered across shards.
+    """
+    from repro.kernels.flash_varlen import flash_varlen_pool_call
+
+    T, H, dh = q.shape
+    K = k_blk.shape[1]
+    loc = jnp.asarray(is_local, bool).reshape(1)
+    lyr = jnp.asarray(layer, jnp.int32).reshape(1)
+    interp = _interpret()
+
+    def local_call(q_l, kb_l, vb_l, pk, pv, kp, rw, nl, ly, lc, qp):
+        H_l, K_l = q_l.shape[1], kb_l.shape[1]
+        G_l = H_l // K_l
+        qr = (q_l.reshape(T, K_l, G_l, dh).transpose(1, 0, 2, 3)
+              .reshape(K_l, T * G_l, dh))
+        out = flash_varlen_pool_call(
+            qr, kb_l.transpose(1, 0, 2), vb_l.transpose(1, 0, 2), qp, pk,
+            pv, kp, rw, nl, ly, lc, softcap=softcap, window=window,
+            interpret=interp)
+        out = (out.reshape(K_l, T, G_l, dh).transpose(1, 0, 2, 3)
+               .reshape(T, H_l, dh))
+        return out.astype(q_l.dtype)
+
+    args = (q, k_blk, v_blk, pool_k, pool_v, kv_pos, rows, n_live, lyr, loc,
+            q_pos)
+    mesh, msize = _mesh_model()
+    if mesh is None:
+        return local_call(*args)
+    _require_divisible("varlen pool attention", m=msize, n_heads=H,
+                       n_kv_heads=K)
+    from repro.jax_compat import shard_map as _shard_map
+    h_spec = P(None, "model", None)
+    pool_spec = P(None, None, "model", None, None)
+    return _shard_map(
+        local_call, mesh=mesh,
+        in_specs=(h_spec, h_spec, h_spec, pool_spec, pool_spec,
+                  P(None, None, "model", None, None, None), P(None),
+                  P(None), P(None), P(None), P(None)),
+        out_specs=h_spec,
+        check_vma=False,
+    )(*args)
 
 
 def ssm_segment_scan(xh, dt, A, Bm, Cm, reset, cap_rows, *, chunk: int = 64):

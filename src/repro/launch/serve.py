@@ -198,6 +198,7 @@ def run_serve(arch: str, system: str, workload: str, rps: float, n: int,
         padded_refresh_calls=stats.padded_refresh_calls,
         packed_reuse_calls=stats.packed_reuse_calls,
         padded_reuse_calls=stats.padded_reuse_calls,
+        reuse_inplace_calls=stats.reuse_inplace_calls,
         warmup_s=warmup_s,
         # retrace sentinel (docs/analysis.md): per-entry compile counts and
         # the post-warmup budget — 0 on the padded path, lazily-compiled

@@ -96,6 +96,8 @@ def check(arch: str, mesh_shape, n: int = 5, seed: int = 0,
                kernels_active=eng.kernels_active, sharing=sharing,
                shared_hits=st_mesh.shared_hits,
                shared_cow_promotes=st_mesh.shared_cow_promotes,
+               packed_reuse_calls=st_mesh.packed_reuse_calls,
+               reuse_inplace_calls=st_mesh.reuse_inplace_calls,
                ok=True, diffs=[])
     if sharing:
         for name in ("shared_hits", "shared_cow_promotes",

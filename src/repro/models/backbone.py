@@ -288,15 +288,21 @@ def serve_reuse_packed(
     cfg: ModelConfig,
     flat_tokens: jax.Array,      # [Tq] int32 packed active-block stream
     flat_positions: jax.Array,   # [Tq] int32 absolute positions
-    cache,                       # PackedKV, leading [L], batch = Tq // Sb
+    cache,                       # leading [L]; batch = Tq // Sb, or see rows
     serve: T.ServeContext,
+    rows: Optional[jax.Array] = None,     # [R] int32 slot table
+    n_live: Optional[jax.Array] = None,   # [1] int32 real requests
 ) -> jax.Array:
     """Token-packed Reuse (whole-iteration packing): the iteration's R active
-    blocks run as ONE ragged ``[R·Sb]`` query stream against their gathered
-    slot caches (``Tq = R·Sb`` rounded to the token bucket by the engine —
-    never a pow2 batch bucket). Attention families run the flat varlen
-    cross-attention; SSM blocks decode recurrently from their cached states
-    (block-exact — the packed win is the exact request count); hybrids
+    blocks run as ONE ragged ``[R·Sb]`` query stream against their slot
+    caches (``Tq = R·Sb`` rounded to the token bucket by the engine — never
+    a pow2 batch bucket). ``cache`` is either gathered (row r is request r)
+    or, for the attention families' kernel path, the whole slot pool read
+    in place through the slot table ``rows`` (the first ``n_live`` real;
+    :func:`repro.models.transformer.forward_block_packed`). Attention
+    families run the varlen cross-attention; SSM blocks decode recurrently
+    from their cached states (block-exact — the packed win is the exact
+    request count); hybrids
     combine both with a causal shared block. Modality-frontend archs take
     this path unchanged: the active block is always text, so the Reuse
     stream is text-only by construction — the frontend prefix participates
@@ -313,10 +319,12 @@ def serve_reuse_packed(
     if cfg.family in ATTN_FAMILIES:
         h = T.forward_block_packed(params["stack"], cfg, xb,
                                    flat_positions.reshape(R, Sb), cache,
-                                   serve=serve)
+                                   serve=serve, rows=rows, n_live=n_live)
     elif cfg.family == "ssm":
+        assert rows is None, "SSM Reuse reads a gathered cache"
         h = _ssm_reuse(params, cfg, xb, cache)
     else:  # hybrid
+        assert rows is None, "hybrid Reuse reads a gathered cache"
         h = HY.forward_block_packed(params["stack"], cfg, xb,
                                     flat_positions.reshape(R, Sb), cache,
                                     serve=serve)

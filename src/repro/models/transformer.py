@@ -408,48 +408,79 @@ def forward_block_packed(
     cfg: ModelConfig,
     xb: jax.Array,                 # [R, Sb, D] embedded active blocks
     block_positions: jax.Array,    # [R, Sb] int32 absolute positions
-    cache: PackedKV,               # leading [L] axis, batch axis = R
+    cache: PackedKV,               # leading [L] axis; see rows
     *,
     serve: ServeContext,
+    rows: Optional[jax.Array] = None,     # [R] int32 slot table
+    n_live: Optional[jax.Array] = None,   # [1] int32 real requests
 ) -> jax.Array:
     """Token-packed Reuse over the layer stack (whole-iteration packing).
 
     The iteration's R active blocks form one ragged ``[R·Sb]`` query stream
     (R is rounded to the token-bucket granularity by the engine — never a
-    pow2 batch bucket). With ``use_flash_kernel`` each layer runs ONE flat
-    cross-attention dispatch: packed queries against the flat per-request
-    ``[retain ; live block]`` KV stream, non-owned KV tiles skipped in-kernel
-    (FLOPs ~ R·Sb·(retain+Sb), not R²·...). Without the kernel, the layer
-    falls back to the exact split-attention math batched over the same R —
-    identical FLOPs, XLA-level dispatch. Bidirectional only (the attention
-    families are bidirectional diffusion LMs; the causal hybrid family has
-    its own packed Reuse in :func:`repro.models.hybrid.forward_block_packed`
-    built on the same flat dispatch)."""
+    pow2 batch bucket). With ``use_flash_kernel`` each layer runs ONE
+    cross-attention dispatch that reads each request's retained K/V in
+    place: ``cache`` is the whole slot pool (``[L, S, ...]``), ``rows`` the
+    slot table and ``n_live`` the count of leading real requests; with
+    ``rows`` None, ``cache`` is a gathered one whose row r is request r.
+    The cache leaves are closed over and the layer is indexed inside the
+    kernel, so no layer's cache is sliced, concatenated or transposed (a
+    scanned Pallas operand would be a per-layer copy of every slot).
+    Without the kernel (``rows`` must be None), the layer falls back to the
+    exact split-attention math batched over the same R — identical FLOPs,
+    XLA-level dispatch. Bidirectional only (the attention families are
+    bidirectional diffusion LMs; the causal hybrid family has its own
+    packed Reuse in :func:`repro.models.hybrid.forward_block_packed`)."""
     R, Sb, D = xb.shape
     cos, sin = L.rope_tables(block_positions, cfg.resolved_head_dim,
                              cfg.rope_theta)
     flags = L.layer_flags(cfg)
-    Cr = cache.k.shape[3]
-    q_seg = jnp.repeat(jnp.arange(R, dtype=jnp.int32), Sb)
-    kv_seg = jnp.repeat(jnp.arange(R, dtype=jnp.int32), Cr + Sb)
 
-    def body(carry, scanned):
-        p, is_local, ck, cv, cpos, cvalid = scanned
-        if serve.use_flash_kernel:
-            x = _reuse_attention_layer_flat(
-                p, carry, cfg, cos, sin, block_positions, is_local,
-                ck, cv, cpos, cvalid, q_seg, kv_seg)
-        else:
+    def mlp(x, p):
+        h2 = L.rms_norm(x, p["mlp_norm"], cfg.rms_eps)
+        y, _ = _mlp(p, h2, cfg)
+        return x + y
+
+    if not serve.use_flash_kernel:
+        assert rows is None, "the split-attention path reads a gathered cache"
+
+        def body(carry, scanned):
+            p, is_local, ck, cv, cpos, cvalid = scanned
             x = reuse_attention_layer(p, carry, cfg, cos, sin,
                                       block_positions, is_local, ck, cv,
                                       cpos, cvalid, "bidirectional",
                                       concat=serve.reuse_concat)
-        h2 = L.rms_norm(x, p["mlp_norm"], cfg.rms_eps)
-        y, _ = _mlp(p, h2, cfg)
-        return x + y, None
+            return mlp(x, p), None
 
-    xb, _ = jax.lax.scan(
-        body, xb, (stack, flags, cache.k, cache.v, cache.pos, cache.valid))
+        xb, _ = jax.lax.scan(
+            body, xb, (stack, flags, cache.k, cache.v, cache.pos,
+                       cache.valid))
+        return xb
+
+    from repro.kernels import ops as kops
+    if rows is None:
+        rows = jnp.arange(R, dtype=jnp.int32)
+        n_live = jnp.full((1,), R, jnp.int32)
+    kv_pos = kops.retained_positions(cache.pos, cache.valid, rows)
+    q_pos = block_positions.reshape(-1)
+
+    def body(carry, scanned):
+        p, is_local, layer = scanned
+        h = L.rms_norm(carry, p["attn_norm"], cfg.rms_eps)
+        q, k, v = _qkv(p, h, cfg, cos, sin)
+        H, K, dh = q.shape[2], k.shape[2], q.shape[3]
+        out = kops.flash_varlen_pool_attention(
+            q.reshape(R * Sb, H, dh), k.reshape(R * Sb, K, dh),
+            v.reshape(R * Sb, K, dh), cache.k, cache.v, kv_pos, rows=rows,
+            n_live=n_live, layer=layer, q_pos=q_pos,
+            window=cfg.sliding_window, is_local=is_local,
+            softcap=cfg.attn_softcap)
+        x = carry + jnp.einsum("bshe,hed->bsd", out.reshape(R, Sb, H, dh),
+                               p["wo"])
+        return mlp(x, p), None
+
+    layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+    xb, _ = jax.lax.scan(body, xb, (stack, flags, layers))
     return xb
 
 
@@ -457,13 +488,13 @@ def _reuse_attention_layer_flat(p, x, cfg: ModelConfig, cos, sin,
                                 block_positions, is_local, ck, cv, cpos,
                                 cvalid, q_seg, kv_seg,
                                 mask_mode: str = "bidirectional"):
-    """One packed-Reuse attention sublayer as a single flat varlen dispatch.
+    """One packed-Reuse attention sublayer as a single flat varlen dispatch
+    over a gathered cache (the hybrid family's causal shared block).
 
     x: [R, Sb, D]; ck/cv: [R, K, Cr, dh] gathered slot caches. The KV stream
     interleaves each request's retained cache with its live block KV —
     requests stay contiguous (segment-ascending), so the cross kernel's
-    tile-skip bounds compute by Σ (retain + Sb) per owning request.
-    ``mask_mode="causal"`` serves the hybrid family's causal shared block."""
+    tile-skip bounds compute by Σ (retain + Sb) per owning request."""
     R, Sb, _ = x.shape
     K, Cr, dh = ck.shape[1], ck.shape[2], ck.shape[3]
     h = L.rms_norm(x, p["attn_norm"], cfg.rms_eps)

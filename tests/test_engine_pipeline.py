@@ -93,6 +93,27 @@ def test_pipelined_is_bit_identical(arch, packed):
                       _run(True, serve, arch=arch))
 
 
+@pytest.mark.parametrize("fault_seed", [None, 3], ids=["clean", "faults"])
+def test_pipelined_in_place_reuse_is_bit_identical(fault_seed):
+    """Packed Reuse that reads the slot pool in place, followed in the next
+    iteration by a Refresh whose pool write donates that pool: the
+    pipelined loop dispatches the write while the Reuse may still read the
+    old buffer, and must land the sync oracle's tokens. With injected
+    faults a retried dispatch reads the pool as it is then."""
+    serve = dataclasses.replace(BASE, varlen_pack=True,
+                                use_flash_kernel=True)
+    sync = _run(False, serve, fault_seed=fault_seed)
+    pipe = _run(True, serve, fault_seed=fault_seed)
+    _assert_identical(sync, pipe)
+    ss = pipe[2]
+    assert ss.reuse_inplace_calls == ss.packed_reuse_calls > 0
+    log = list(ss.iter_log)
+    assert any(a["n_reuse"] and b["n_refresh"]
+               for a, b in zip(log, log[1:]))
+    if fault_seed is not None:
+        assert ss.dispatch_retries > 0
+
+
 def test_bit_identical_under_preemption_and_faults():
     """Chaos + starvation preemption: in-flight commits whose request was
     preempted must be discarded EXACTLY as the oracle overwrites them —

@@ -140,6 +140,13 @@ def test_shard_agreement_subprocess(arch, extra, tmp_path):
     rec = json.loads(out.read_text())
     assert rec["ok"], rec
     assert rec["mesh_devices"] == 2, rec
+    if "--kernels" in extra and arch == "llada-8b":
+        # packed Reuse reads the head-sharded pool in place on a model
+        # mesh; a pool whose slot axis is split over data is gathered
+        inplace = rec["reuse_inplace_calls"]
+        assert rec["packed_reuse_calls"] > 0, rec
+        assert inplace == (0 if "2,1" in extra
+                           else rec["packed_reuse_calls"]), rec
     if "--sharing" in extra:
         # shard_check itself fails on zero hits, but pin it here too:
         # a vacuous agreement run must never count as coverage

@@ -695,6 +695,80 @@ def test_cross_kernel_matches_masked_reference():
             np.asarray(out), ref_out.reshape(Tq, H, dh), atol=2e-5)
 
 
+# (G, softcap, window, is_local, Cr, kv_tile): GQA, softcap, a sliding
+# window on a local and on a global layer, a retained axis that is no
+# multiple of 128, and one cut into three tiles
+POOL_CASES = {
+    "g1": (1, 0.0, 0, False, 24, 1024),
+    "g4-softcap": (4, 30.0, 0, False, 40, 1024),
+    "window-local": (1, 0.0, 8, True, 24, 1024),
+    "window-global-g4": (4, 0.0, 8, False, 24, 1024),
+    "tiled": (1, 0.0, 0, False, 96, 32),
+}
+
+
+@pytest.mark.parametrize("case", list(POOL_CASES))
+def test_pool_kernel_matches_reference(case):
+    """The slot-table kernel reads each request's retained K/V in place
+    from a pool and must equal a float32 cross-attention over [retained ;
+    live block]: ragged R with padding rows on the scratch slot (their
+    output is zero), a slot table out of order that repeats a slot (a
+    shared prefix), per-head valid masks that leave a head two rows."""
+    G, softcap, window, is_local, Cr, kv_tile = POOL_CASES[case]
+    seed = list(POOL_CASES).index(case)
+    rng = np.random.default_rng(seed)
+    Lyr, S, K, dh, Sb = 3, 6, 2, 16, 8
+    H, R, n_live, scratch, layer = K * G, 5, 3, S - 1, 1
+    rows = np.array([3, 0, 3, scratch, scratch], np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    pool_k = np.asarray(jax.random.normal(ks[0], (Lyr, S, K, Cr, dh)))
+    pool_v = np.asarray(jax.random.normal(ks[1], (Lyr, S, K, Cr, dh)))
+    q = np.asarray(jax.random.normal(ks[2], (R * Sb, H, dh)))
+    kb = np.asarray(jax.random.normal(ks[3], (R * Sb, K, dh)))
+    vb = np.asarray(jax.random.normal(ks[4], (R * Sb, K, dh)))
+    pos = rng.integers(0, 64, (Lyr, S, K, Cr)).astype(np.int32)
+    valid = rng.random((Lyr, S, K, Cr)) > 0.3
+    valid[:, :, 1, 2:] = False
+    valid[:, scratch] = False
+    bstarts = rng.integers(0, 56, R)
+    q_pos = (bstarts[:, None] + np.arange(Sb)).reshape(-1).astype(np.int32)
+    kv_pos = ops.retained_positions(jnp.asarray(pos), jnp.asarray(valid),
+                                    jnp.asarray(rows), kv_tile=kv_tile)
+    assert kv_pos.shape[3] == (3 if case == "tiled" else 1)
+    out = np.asarray(ops.flash_varlen_pool_attention(
+        jnp.asarray(q), jnp.asarray(kb), jnp.asarray(vb),
+        jnp.asarray(pool_k), jnp.asarray(pool_v), kv_pos,
+        rows=jnp.asarray(rows), n_live=jnp.asarray([n_live], jnp.int32),
+        layer=jnp.int32(layer), q_pos=jnp.asarray(q_pos), window=window,
+        is_local=is_local, softcap=softcap))
+    for r in range(R):
+        got = out[r * Sb:(r + 1) * Sb]
+        if r >= n_live:
+            assert not got.any()
+            continue
+        blk = slice(r * Sb, (r + 1) * Sb)
+        k_all = np.concatenate([pool_k[layer, rows[r]],
+                                kb[blk].transpose(1, 0, 2)], axis=1)
+        v_all = np.concatenate([pool_v[layer, rows[r]],
+                                vb[blk].transpose(1, 0, 2)], axis=1)
+        kp = np.concatenate([pos[layer, rows[r]],
+                             np.broadcast_to(q_pos[blk], (K, Sb))], axis=1)
+        ok = np.concatenate([valid[layer, rows[r]], np.ones((K, Sb), bool)],
+                            axis=1)[:, None, :]            # [K, 1, T]
+        if window:
+            dist = np.abs(q_pos[blk][None, :, None] - kp[:, None, :])
+            ok = ok & np.where(is_local, dist <= window, True)
+        z = np.einsum("tkgd,ksd->kgts", q[blk].reshape(Sb, K, G, dh),
+                      k_all) * dh ** -0.5
+        if softcap:
+            z = softcap * np.tanh(z / softcap)
+        z = np.where(ok[:, None], z, -np.inf)
+        p = np.exp(z - z.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        want = np.einsum("kgts,ksd->tkgd", p, v_all).reshape(Sb, H, dh)
+        np.testing.assert_allclose(got, want, atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # logit stage: packed decode vs the padded oracle
 # ---------------------------------------------------------------------------

@@ -85,6 +85,24 @@ def test_flash_varlen_cross_compiles(spec, R, retain, kv_tile):
         spec((1,), BOOL), q_tile=128, kv_tile=kv_tile, interpret=False))
 
 
+# packed Reuse in place: R blocks of 32 queries against the retained K/V of
+# an [L, S, K, Cr, dh] pool; the chat cell's 16 x 13 x 32 x 608 x 128 pool
+# (retained axis whole), and a GQA, windowed pool whose axis takes 2 tiles
+@pytest.mark.parametrize("Lyr,S,K,g,Cr,n_c,R,window", [
+    (16, 13, 32, 1, 608, 1, 4, 0),
+    (4, 9, 8, 4, 1024, 2, 3, 128),
+])
+def test_flash_varlen_pool_compiles(spec, Lyr, S, K, g, Cr, n_c, R, window):
+    dh, Sb = 128, 32
+    _compiled_with_mosaic(FV.flash_varlen_pool_call.lower(
+        spec((K, R * Sb * g, dh), BF), spec((K, R * Sb, dh), BF),
+        spec((K, R * Sb, dh), BF), spec((R * Sb,), I32),
+        spec((Lyr, S, K, Cr, dh), BF), spec((Lyr, S, K, Cr, dh), BF),
+        spec((Lyr, R, K, n_c, 1, Cr // n_c), I32), spec((R,), I32),
+        spec((1,), I32), spec((1,), I32), spec((1,), BOOL), window=window,
+        interpret=False))
+
+
 @pytest.mark.parametrize("T,chunk,R", [(1024, 64, 4), (256, 128, 8)])
 def test_ssm_segment_scan_compiles(spec, T, chunk, R):
     H, P, N = 24, 64, 128                     # mamba2-130m
