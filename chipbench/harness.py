@@ -320,9 +320,10 @@ def build(cell: SP.Cell, seed: int, *, hbm_bytes: Optional[int] = None,
     compiles = CompileCounter()
     compiles.on = True
     dev = jax.devices()[0]
-    cfg = C.model_config(cell.config, **(cfg_overrides or {}))
-    dims = C.dims_of(cfg)
-    if not cfg_overrides and dims != C.dims(cell.config):
+    arch = cell.arch
+    cfg = C.model_config(cell.config, arch, **(cfg_overrides or {}))
+    dims = C.dims_of(cfg, cell.config, arch)
+    if not cfg_overrides and dims != C.dims(cell.config, arch):
         raise ValueError(f"{cfg.name}: the program's ModelConfig differs "
                          f"from the configuration file")
     budget = hbm_bytes

@@ -1,16 +1,13 @@
-"""A cell at a size the CPU can hold, for the tests: two layers of width
-128 over a 4,096-token vocabulary (bf16 kept), the mix's
-prompts 8-40 tokens with 64-token answers, a small pool, a short window.
-Kernels run interpreted there; the structure of the run is the chip's."""
+"""A cell at a size the CPU can hold, for the tests: the widths of the
+configuration's architecture module (``REHEARSAL``; LLaDA's two layers of
+width 128 over a 4,096-token vocabulary, bf16 kept), the mix's prompts
+8-40 tokens with 64-token answers, a small pool, a short window. Kernels
+run interpreted there; the structure of the run is the chip's."""
 from __future__ import annotations
 
 import copy
 import time
 
-REDUCED = dict(n_layers=2, d_model=128, n_heads=4, n_kv_heads=4, d_ff=256,
-               vocab_size=4096, head_dim=32, dtype="bfloat16")
-REDUCED_SSM = dict(n_layers=2, d_model=64, vocab_size=256, ssm_state=16,
-                   ssm_head_dim=8, dtype="bfloat16")
 HBM = 16 << 30
 
 
@@ -37,10 +34,12 @@ GAP_LIMIT = 1e-2
 
 
 def run(workload: str, seed: int = 7, seconds: float = 5.0,
-        trace: bool = False, root=None, trace_dir=None, ssm=False):
+        trace: bool = False, root=None, trace_dir=None):
     from chipbench import run as R
+    from chipbench import spec as SP
     kw = {} if root is None else {"root": root}
+    arch = SP.load_cell(workload, root).arch
     return R.execute(workload, seed, seconds, trace,
                      t_proc0=time.perf_counter(), hbm_bytes=HBM,
-                     cfg_overrides=REDUCED_SSM if ssm else REDUCED,
+                     cfg_overrides=arch.REHEARSAL,
                      cell_overrides=shrink, trace_dir=trace_dir, **kw)

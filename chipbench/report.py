@@ -1,7 +1,6 @@
 """Turns one measured run into the printed lines and the result line."""
 from __future__ import annotations
 
-import importlib.util
 import json
 import math
 import shutil
@@ -16,12 +15,7 @@ def read_metric(run, entry: dict) -> Optional[float]:
     """Load ``chipbench/metrics/<name>.py`` and call its ``read(run)``; a
     reader that finds nothing to read returns None."""
     path = SP.metric_path(run.cell.root, entry["name"])
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + entry["name"].replace(".", "_").replace(
-            "-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    v = mod.read(run)
+    v = SP.load_module(path).read(run)
     if v is None or (isinstance(v, float) and math.isnan(v)):
         return None
     return M.finite(float(v))

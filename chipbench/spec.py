@@ -3,7 +3,8 @@
 A cell (``workloads`` entry) names a configuration and a traffic mix. Its
 files, relative to the checkout root:
 
-* ``configs[].file`` — the model configuration as it is run;
+* ``configs[].file`` — the model configuration as it is run; its
+  ``arch_module`` key names the architecture's module (below);
 * ``chipbench/traffic/<traffic>.json`` — the mix's lengths, arrivals and
   denoising schedule, read by the one generator in ``traffic.py``;
 * ``chipbench/cells/<workload>.json`` — the cell's rate or request count,
@@ -12,9 +13,21 @@ files, relative to the checkout root:
 
 Adding a cell, configuration, traffic mix or metric adds files and entries;
 no file here changes.
+
+A configuration of an architecture the benchmark has not run yet brings
+its module as a file too, ``chipbench/archs/<arch>.py``, named by the
+configuration's ``arch_module`` and loaded by path (:func:`load_module`).
+Everything that depends on the architecture lives there
+(``archs/__init__.py`` lists what a module provides): the leaves of the
+weights' layer stack, which steps of a block the reference replays as a
+Refresh and which as a Reuse, the reference's layers, the operations per
+token, the ``ModelConfig`` fields it reads and the sizes of the CPU
+rehearsal. The file's ``program`` may set any ``ModelConfig`` field.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -40,6 +53,11 @@ class Cell:
     @property
     def name(self) -> str:
         return self.workload["name"]
+
+    @property
+    def arch(self):
+        """The configuration's architecture module (``arch_module``)."""
+        return load_module(self.root / self.config["arch_module"])
 
     @property
     def chips(self) -> int:
@@ -76,6 +94,22 @@ def load_cell(name: str, root: Optional[Path] = None) -> Cell:
     traffic = load_json(root / "chipbench" / "traffic" / f"{w['traffic']}.json")
     cell = load_json(root / "chipbench" / "cells" / f"{name}.json")
     return Cell(root, bench, w, config, traffic, cell)
+
+
+def load_module(path: Path):
+    """A module of the benchmark's loaded from its file, once a process for
+    each path: a new architecture or metric is a new file, found by the
+    name ``BENCHMARK.json`` or a configuration gives it."""
+    return _load(Path(path).resolve())
+
+
+@functools.cache
+def _load(path: Path):
+    name = "chipbench_" + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_path(root: Path, name: str) -> Path:

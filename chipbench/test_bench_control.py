@@ -21,7 +21,7 @@ def test_control_fails_and_program_passes_on_three_seeds():
     from chipbench import traffic as TR
     cell = rehearsal.shrink(SP.load_cell("llada-8b-1chip.chat"))
     b = H.build(cell, 31, hbm_bytes=rehearsal.HBM,
-                cfg_overrides=rehearsal.REDUCED)
+                cfg_overrides=cell.arch.REHEARSAL)
     b.times["t_proc0"] = 0.0
     for i, seed in enumerate((31, 32, 33)):
         if i:

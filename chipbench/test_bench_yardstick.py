@@ -9,13 +9,16 @@ from pathlib import Path
 import pytest
 
 from chipbench import measure as M
+from chipbench import spec as SP
 from chipbench import traffic as TR
 from chipbench import work as W
 from chipbench.report import read_metric
 
 HERE = Path(__file__).resolve().parent
+LLADA = SP.load_module(HERE / "archs" / "llada.py")
 LLADA16 = dict(family="dense", n_layers=16, d_model=4096, n_heads=32,
-               n_kv_heads=32, head_dim=128, d_ff=12288, vocab_size=126464)
+               n_kv_heads=32, head_dim=128, d_ff=12288, vocab_size=126464,
+               arch=LLADA)
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -72,7 +75,7 @@ def test_failed_requests_miss_every_limit():
 
 def test_work_counts_by_hand_at_llada_widths():
     per_tok = 2 * 16 * (4096 * 32 * 128 * 4 + 3 * 4096 * 12288)
-    assert W.matmul_flops_per_token(LLADA16) == per_tok
+    assert LLADA.matmul_flops_per_token(LLADA16) == per_tok
     assert per_tok == pytest.approx(6.98e9, rel=1e-3)
     assert W.logit_flops_per_row(LLADA16) == 2 * 4096 * 126464
     L, sb, retain = 556, 32, 608
